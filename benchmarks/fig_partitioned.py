@@ -3,11 +3,14 @@
 For each (dataset, app) the single-device full-graph epoch is the
 baseline; the partitioned rows train the same model across 2/4/8
 host-emulated shards (ring execution) plus a delayed-halo row for GCN.
-Emulated devices need ``XLA_FLAGS=--xla_force_host_platform_device_count``
-set BEFORE jax imports, so the shard sweep re-execs itself in one child
-process and streams rows back; a child killed by a signal (the known
-host-platform emulation crash) downgrades to a skip note instead of
-failing the whole benchmark run.
+On the CPU the shards are emulated devices, which need
+``XLA_FLAGS=--xla_force_host_platform_device_count`` set BEFORE jax
+imports, so the shard sweep re-execs itself in one child process and
+streams rows back; a child killed by a signal (the known host-platform
+emulation crash) downgrades to a skip note instead of failing the whole
+benchmark run. On an accelerator the parent already holds the chips, so
+the sweep runs in-process over shard counts no larger than the device
+count.
 
 Reported per row: measured epoch wall time (compile excluded — the
 train loops warm up before timing), the speedup over the single-device
@@ -19,6 +22,9 @@ NOTE: on host-EMULATED devices all "shards" share one CPU's cores, so
 wall-clock speedups > 1 are not expected at these scales — the sweep
 tracks the communication/padding overhead trend across shard counts
 (the real-hardware signal), not raw speed.
+
+Run from the repository root (``python -m benchmarks.run --only
+partitioned``): the emulation child imports this module.
 """
 from __future__ import annotations
 
@@ -54,92 +60,124 @@ else:
     EPOCHS = 3
     HALO = (4,)          # gcn halo-staleness rows
 
+def sweep(cfg: dict):
+    """The shard sweep; yields one message dict per result row.
+
+    Runs on whatever devices this process's JAX sees: the emulated host
+    devices of the child :func:`main` starts on a CPU, or the chips of
+    the process that calls it on an accelerator.
+    """
+    import jax
+
+    from repro.core import planner
+    from repro.data import make_node_dataset
+    from repro.launch.mesh import make_shard_mesh
+    from repro.models.gnn import gat, gcn, sage
+    from repro.models.gnn.train import train_partitioned
+    from repro.obs import metrics as _metrics
+    from repro.optim import Precision
+
+    mods = {"gcn": gcn, "sage": sage, "gat": gat}
+    g, feats, labels, tm, vm, nc = make_node_dataset(cfg["dataset"])
+    for app in cfg["apps"]:
+        mod = mods[app]
+        params = mod.init(jax.random.PRNGKey(0), feats.shape[1], 64, nc)
+        for S in cfg["shards"]:
+            mesh = make_shard_mesh(S)
+            _, hist = train_partitioned(
+                mod.forward_partitioned, params, g, feats, labels, tm,
+                n_shards=S, mesh=mesh, epochs=cfg["epochs"], drop=0.0,
+                seed=1)
+            pg = planner.get_plan_cache(g).partition(S, "contiguous")
+            yield {"kind": "row", "app": app, "shards": S, "halo": 0,
+                   "epoch_time": hist["epoch_time"][-1],
+                   "loss": hist["loss"][-1],
+                   "cut": pg.stats.cut_fraction, "eb": pg.stats.eb}
+    # delayed-halo rows (gcn only): the reported time is a STALE epoch —
+    # the ring-free step the staleness knob buys
+    for k in cfg["halo"]:
+        S = max(cfg["shards"])
+        mesh = make_shard_mesh(S)
+        params = gcn.init(jax.random.PRNGKey(0), feats.shape[1], 64, nc)
+        _, hist = train_partitioned(
+            gcn.forward_partitioned, params, g, feats, labels, tm,
+            n_shards=S, mesh=mesh, epochs=cfg["epochs"] * 2, drop=0.0,
+            halo_staleness=k, init_halo_fn=gcn.init_halo, seed=1)
+        # same estimator as every other row: the LAST epoch of the kind
+        # the row reports (here: the last stale, i.e. ring-free, epoch)
+        stale_epochs = [t for t, r in zip(hist["epoch_time"],
+                                          hist["refreshed"]) if not r]
+        yield {"kind": "row", "app": "gcn", "shards": S, "halo": k,
+               "epoch_time": (stale_epochs[-1] if stale_epochs
+                              else hist["epoch_time"][-1]),
+               "loss": hist["loss"][-1], "cut": 0.0, "eb": 0}
+    # precision x compression sweep (gcn): exchange bytes come from the
+    # obs metrics registry (comm.ring.*_bytes), step time and final-loss
+    # delta vs the fp32 row ride along (DESIGN.md §12)
+    S = 4 if 4 in cfg["shards"] else max(cfg["shards"])
+    mesh = make_shard_mesh(S)
+    params = gcn.init(jax.random.PRNGKey(0), feats.shape[1], 64, nc)
+    base_loss = None
+    for pname, comm in (("fp32", "none"), ("bf16", "none"),
+                        ("fp32", "int8"), ("bf16", "int8")):
+        prec = Precision.parse(pname, comm=comm)
+        prev = _metrics.set_enabled(True)
+        _metrics.reset_metrics()
+        _, hist = train_partitioned(
+            gcn.forward_partitioned, params, g, feats, labels, tm,
+            n_shards=S, mesh=mesh, epochs=cfg["epochs"], drop=0.0, seed=1,
+            precision=prec,
+            init_comm_fn=gcn.init_comm if comm == "int8" else None)
+        snap = _metrics.snapshot()
+        _metrics.set_enabled(prev)
+        loss = hist["loss"][-1]
+        if base_loss is None:
+            base_loss = loss
+        yield {"kind": "prec_row", "app": "gcn", "shards": S,
+               "precision": prec.tag(),
+               "epoch_time": hist["epoch_time"][-1],
+               "loss": loss, "loss_delta": loss - base_loss,
+               "raw_bytes": snap.get("comm.ring.raw_bytes",
+                                     {}).get("value", 0),
+               "wire_bytes": snap.get("comm.ring.wire_bytes",
+                                      {}).get("value", 0)}
+
+
+# the emulation child: forces the host device count BEFORE jax imports,
+# then streams the sweep's messages back as JSON lines
 _CHILD = r"""
 import json, os, sys
 cfg = json.loads(sys.argv[1])
 os.environ["XLA_FLAGS"] = (
     "--xla_force_host_platform_device_count=%d" % max(cfg["shards"]))
-import numpy as np, jax
+from benchmarks.fig_partitioned import sweep
 from repro.core import planner
-from repro.data import make_node_dataset
-from repro.launch.mesh import make_shard_mesh
-from repro.models.gnn import gcn, sage, gat
-from repro.models.gnn.train import train_partitioned
-
-mods = {"gcn": gcn, "sage": sage, "gat": gat}
-g, feats, labels, tm, vm, nc = make_node_dataset(cfg["dataset"])
-for app in cfg["apps"]:
-    mod = mods[app]
-    params = mod.init(jax.random.PRNGKey(0), feats.shape[1], 64, nc)
-    for S in cfg["shards"]:
-        mesh = make_shard_mesh(S)
-        _, hist = train_partitioned(
-            mod.forward_partitioned, params, g, feats, labels, tm,
-            n_shards=S, mesh=mesh, epochs=cfg["epochs"], drop=0.0, seed=1)
-        pg = planner.get_plan_cache(g).partition(S, "contiguous")
-        print(json.dumps({"kind": "row", "app": app, "shards": S,
-                          "halo": 0,
-                          "epoch_time": hist["epoch_time"][-1],
-                          "loss": hist["loss"][-1],
-                          "cut": pg.stats.cut_fraction,
-                          "eb": pg.stats.eb}), flush=True)
-# delayed-halo rows (gcn only): the reported time is a STALE epoch —
-# the ring-free step the staleness knob buys
-for k in cfg["halo"]:
-    S = max(cfg["shards"])
-    mesh = make_shard_mesh(S)
-    params = gcn.init(jax.random.PRNGKey(0), feats.shape[1], 64, nc)
-    _, hist = train_partitioned(
-        gcn.forward_partitioned, params, g, feats, labels, tm,
-        n_shards=S, mesh=mesh, epochs=cfg["epochs"] * 2, drop=0.0,
-        halo_staleness=k, init_halo_fn=gcn.init_halo, seed=1)
-    # same estimator as every other row: the LAST epoch of the kind the
-    # row reports (here: the last stale, i.e. ring-free, epoch)
-    stale_epochs = [t for t, r in zip(hist["epoch_time"],
-                                      hist["refreshed"]) if not r]
-    print(json.dumps({"kind": "row", "app": "gcn", "shards": S,
-                      "halo": k,
-                      "epoch_time": (stale_epochs[-1] if stale_epochs
-                                     else hist["epoch_time"][-1]),
-                      "loss": hist["loss"][-1], "cut": 0.0,
-                      "eb": 0}), flush=True)
-# precision x compression sweep (gcn): exchange bytes come from the obs
-# metrics registry (comm.ring.*_bytes), step time and final-loss delta
-# vs the fp32 row ride along (DESIGN.md SS12)
-from repro.obs import metrics as _metrics
-from repro.optim import Precision
-S = 4 if 4 in cfg["shards"] else max(cfg["shards"])
-mesh = make_shard_mesh(S)
-params = gcn.init(jax.random.PRNGKey(0), feats.shape[1], 64, nc)
-base_loss = None
-for pname, comm in (("fp32", "none"), ("bf16", "none"),
-                    ("fp32", "int8"), ("bf16", "int8")):
-    prec = Precision.parse(pname, comm=comm)
-    prev = _metrics.set_enabled(True)
-    _metrics.reset_metrics()
-    _, hist = train_partitioned(
-        gcn.forward_partitioned, params, g, feats, labels, tm,
-        n_shards=S, mesh=mesh, epochs=cfg["epochs"], drop=0.0, seed=1,
-        precision=prec,
-        init_comm_fn=gcn.init_comm if comm == "int8" else None)
-    snap = _metrics.snapshot()
-    _metrics.set_enabled(prev)
-    loss = hist["loss"][-1]
-    if base_loss is None:
-        base_loss = loss
-    print(json.dumps({"kind": "prec_row", "app": "gcn", "shards": S,
-                      "precision": prec.tag(),
-                      "epoch_time": hist["epoch_time"][-1],
-                      "loss": loss, "loss_delta": loss - base_loss,
-                      "raw_bytes": snap.get("comm.ring.raw_bytes",
-                                            {}).get("value", 0),
-                      "wire_bytes": snap.get("comm.ring.wire_bytes",
-                                             {}).get("value", 0)}),
-          flush=True)
+for msg in sweep(cfg):
+    print(json.dumps(msg), flush=True)
 print(json.dumps({"kind": "plans",
                   "plans": {f"{op}|{req}": dict(cnt) for (op, req), cnt
                             in planner.plan_log().items()}}), flush=True)
 """
+
+
+def _emulated_sweep(cfg: dict):
+    """Messages of :func:`sweep` run in a host-emulation child, or None
+    when the child died of a signal (the known emulation crash)."""
+    env = dict(os.environ)
+    env["PYTHONPATH"] = "src"
+    env.pop("XLA_FLAGS", None)
+    r = subprocess.run([sys.executable, "-c", _CHILD, json.dumps(cfg)],
+                       env=env, capture_output=True, text=True,
+                       timeout=3600)
+    if r.returncode < 0:
+        print(f"# partitioned sweep skipped: emulation subprocess died "
+              f"with signal {-r.returncode}", file=sys.stderr)
+        return None
+    if r.returncode != 0:
+        print(r.stderr[-3000:], file=sys.stderr)
+        raise RuntimeError("partitioned benchmark child failed")
+    return [json.loads(line) for line in r.stdout.splitlines()
+            if line.strip().startswith("{")]
 
 
 def _baseline(dataset: str, apps, epochs: int) -> dict:
@@ -234,29 +272,26 @@ def powerlaw_ring_rows() -> None:
 
 
 def main() -> None:
+    import jax
+
     base = _baseline(DATASET, APPS, EPOCHS)
     powerlaw_ring_rows()
     cfg = {"dataset": DATASET, "shards": list(SHARDS), "apps": list(APPS),
            "epochs": EPOCHS, "halo": list(HALO)}
-    env = dict(os.environ)
-    env["PYTHONPATH"] = "src"
-    env.pop("XLA_FLAGS", None)
-    r = subprocess.run([sys.executable, "-c", _CHILD, json.dumps(cfg)],
-                       env=env, capture_output=True, text=True,
-                       timeout=3600)
-    if r.returncode < 0:
-        print(f"# partitioned sweep skipped: emulation subprocess died "
-              f"with signal {-r.returncode}", file=sys.stderr)
-        return
-    if r.returncode != 0:
-        print(r.stderr[-3000:], file=sys.stderr)
-        raise RuntimeError("partitioned benchmark child failed")
-    from repro.core import planner
-    for line in r.stdout.splitlines():
-        line = line.strip()
-        if not line.startswith("{"):
-            continue
-        msg = json.loads(line)
+    if jax.default_backend() == "cpu":
+        msgs = _emulated_sweep(cfg)
+        if msgs is None:
+            return
+    else:
+        # this process already holds the chips: sweep them in-process,
+        # never in a child that would find them taken
+        n_dev = len(jax.devices())
+        cfg["shards"] = [S for S in SHARDS if S <= n_dev]
+        if not cfg["shards"]:
+            raise RuntimeError(f"partitioned sweep needs at least "
+                               f"{min(SHARDS)} devices, have {n_dev}")
+        msgs = sweep(cfg)
+    for msg in msgs:
         if msg["kind"] == "row":
             app, S, k = msg["app"], msg["shards"], msg["halo"]
             name = f"figp_{DATASET}_{app}_s{S}"
@@ -280,8 +315,9 @@ def main() -> None:
                       f" wire={msg['wire_bytes']}"
                       f" comp={ratio:.2f}x"))
         elif msg["kind"] == "plans":
-            # replay the child's decisions into the parent's plan log so
-            # the BENCH json reports them like every other section
+            # replay the emulation child's decisions into this process's
+            # plan log so the BENCH json reports them like every section
+            from repro.core import planner
             for key, counts in msg["plans"].items():
                 op, req = key.split("|", 1)
                 for chosen, cnt in counts.items():

@@ -67,7 +67,10 @@ def main() -> None:
 
     from repro.core import planner
     from repro import obs
+    from repro.launch.compile_cache import setup_compile_cache
     from . import common
+
+    setup_compile_cache()
 
     # the JSON contract includes the metrics snapshot (pad-ratio gauges,
     # comm counters) — force telemetry on so BENCH_*.json always carries

@@ -12,6 +12,7 @@ import numpy as np
 
 from repro.checkpoint import CheckpointManager
 from repro.data import make_node_dataset
+from repro.launch.compile_cache import setup_compile_cache
 from repro.models.gnn import sage, make_bundle
 from repro.models.gnn.train import make_train_step
 from repro.substrate.nn import accuracy
@@ -28,6 +29,7 @@ def main():
                          "pick per op (pin 'push'/'ell' to reproduce the "
                          "paper's baseline/optimized runs)")
     args = ap.parse_args()
+    setup_compile_cache()
 
     g, feats, labels, tm, vm, nc = make_node_dataset(args.dataset)
     bundle = make_bundle(g)

@@ -22,6 +22,7 @@ import jax
 import jax.numpy as jnp
 
 from . import planner
+from ..compat import trace_state_clean
 from ..obs.events import timed as _timed
 from .binary_reduce import gspmm
 from .blocks import BlockGraph, block_gspmm
@@ -291,7 +292,7 @@ def fused_attention(g: Graph, el: jnp.ndarray, er: jnp.ndarray,
         # else peek, demoting to the canonical jnp pipeline when the
         # pack was never prebuilt (same idiom as hetero's skew packs)
         cache = planner.get_plan_cache(g)
-        pack = (cache.ell_ragged() if jax.core.trace_state_clean()
+        pack = (cache.ell_ragged() if trace_state_clean()
                 else cache.peek("ell_ragged"))
         if pack is None:
             chosen = "fused"

@@ -49,6 +49,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from . import planner
+from ..compat import trace_state_clean
 from . import strategies as S
 from ..obs.events import timed as _timed
 from .binary_reduce import parse_op, _execute
@@ -418,7 +419,7 @@ def _skew_classes(rg: RelGraph):
     got = _SKEW_CLASSES.get(rg.g, _MISSING)
     if got is not _MISSING:
         return got
-    if not jax.core.trace_state_clean() or planner.graph_is_traced(rg.g):
+    if not trace_state_clean() or planner.graph_is_traced(rg.g):
         return None                 # don't build (or memoize) in-trace
     classes = _build_skew_classes(rg)
     _SKEW_CLASSES[rg.g] = classes   # memoize None too: not-skewed is final
@@ -644,7 +645,7 @@ def hetero_gspmm(rg: RelGraph, u: jnp.ndarray, *,
     # operands: a jitted function that closes over everything still
     # traces, and np→jnp conversions inside it (a pack build, autotune
     # measurement) would leak trace-bound values into the cache
-    eager = (jax.core.trace_state_clean()
+    eager = (trace_state_clean()
              and not any(planner._is_traced(x)
                          for x in (rg.g.src, u, w, basis, coeff, e)
                          if x is not None))

@@ -46,13 +46,14 @@ import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
 
 from .graph import Graph
+from ..compat import pvary, shard_map
 from ..obs import metrics as _metrics
 from ..optim.compression import compress_payload, wire_bytes
 
 __all__ = ["PartitionStats", "PartitionedGraph", "build_partition",
            "ring_gspmm", "ring_edge_values", "bucket_softmax",
            "local_gspmm", "ring_gspmm_delayed", "ring_reference",
-           "PARTITION_MODES", "COMM_MODES"]
+           "require_auto_mesh", "PARTITION_MODES", "COMM_MODES"]
 
 PARTITION_MODES = ("contiguous", "hash", "uniform")
 COMM_MODES = ("none", "int8")
@@ -329,13 +330,6 @@ def _edge_dot(xg, cg, mk, head_rank):
     return jnp.where(mask, dw, jnp.zeros((), dw.dtype))
 
 
-def _maybe_pvary(x, axis):
-    # mark accumulators device-varying so fori_loop carry types match
-    # after ppermute on jax versions with explicit vma tracking
-    pvary = getattr(jax.lax, "pvary", None)
-    return pvary(x, (axis,)) if pvary is not None else x
-
-
 def _fwd_perm(S):
     return [(k, (k + 1) % S) for k in range(S)]
 
@@ -424,12 +418,26 @@ def _ring_bwd_emu(pg: PartitionedGraph, x, w, ct):
     return dx, jnp.stack(dws).astype(w.dtype)
 
 
+def require_auto_mesh(mesh: Mesh) -> None:
+    """Reject a mesh with Explicit (or Manual) axes. On Explicit axes the
+    ring's forward runs, but its custom VJP asks for a mesh context and
+    plain indexing of its outputs cannot resolve their sharding;
+    ``jax.make_mesh`` builds Explicit axes by default, so say how to get
+    Auto ones."""
+    types = tuple(mesh.axis_types)
+    if any(t != jax.sharding.AxisType.Auto for t in types):
+        raise ValueError(
+            f"ring execution needs a mesh with Auto axes, got axis types "
+            f"{types}; build it with repro.launch.mesh.make_mesh or pass "
+            f"axis_types=(jax.sharding.AxisType.Auto,) * {len(types)} to "
+            f"jax.make_mesh")
+
+
 def _node_spec(axis, ndim):
     return P(axis, *([None] * (ndim - 1)))
 
 
 def _ring_fwd_mesh(pg: PartitionedGraph, mesh, axis, x, w):
-    from jax.experimental.shard_map import shard_map
     S, rows, eb = pg.n_shards, pg.rows, pg.eb
     feat = x.shape[1:]
     xs = x.reshape((S, rows) + feat)
@@ -441,8 +449,7 @@ def _ring_fwd_mesh(pg: PartitionedGraph, mesh, axis, x, w):
         me = jax.lax.axis_index(axis)
         block = xb[0]
         sl, dl, mk, wb = sl[0], dl[0], mk[0], wb[0]
-        out = _maybe_pvary(jnp.zeros((rows,) + feat,
-                                     _acc_dtype(x.dtype)), axis)
+        out = pvary(jnp.zeros((rows,) + feat, _acc_dtype(x.dtype)), axis)
 
         # static unroll (S is small): each stage slices its bucket to
         # the diagonal's max real width, and the ring stops after the
@@ -475,7 +482,6 @@ def _ring_bwd_mesh(pg: PartitionedGraph, mesh, axis, x, w, ct):
     """The transposed ring, one pass: cotangent blocks (with their
     weight-bucket rows) rotate BACKWARD for ∂x while source blocks
     rotate forward for ∂w; src/dst bucket roles are swapped for ∂x."""
-    from jax.experimental.shard_map import shard_map
     S, rows, eb = pg.n_shards, pg.rows, pg.eb
     feat = x.shape[1:]
     head_rank = w.ndim - 3
@@ -495,9 +501,8 @@ def _ring_bwd_mesh(pg: PartitionedGraph, mesh, axis, x, w, ct):
         wrow = wb[0]                       # (S, eb[, H]) — my dst row
         sl, dl, mk = sl[0], dl[0], mk[0]   # buckets (me, :)
         slt, dlt, mkt = slt[0], dlt[0], mkt[0]   # buckets (:, me)
-        dx = _maybe_pvary(jnp.zeros((rows,) + feat,
-                                    _acc_dtype(x.dtype)), axis)
-        dw = _maybe_pvary(jnp.zeros(wrow.shape, w.dtype), axis)
+        dx = pvary(jnp.zeros((rows,) + feat, _acc_dtype(x.dtype)), axis)
+        dw = pvary(jnp.zeros(wrow.shape, w.dtype), axis)
         ctblock, wblock = ct_local, wrow
 
         # static unroll mirroring the forward: at stage s both the ∂x
@@ -558,6 +563,8 @@ def _ring_call(pg: PartitionedGraph, x, w, mesh, axis):
         f.defvjp(lambda x, w: (_ring_fwd_emu(pg, x, w), (x, w)),
                  lambda res, ct: _ring_bwd_emu(pg, *res, ct))
         return f(x, w)
+
+    require_auto_mesh(mesh)
 
     @jax.custom_vjp
     def f(x, w):
@@ -675,7 +682,6 @@ def _rev_bwd_emu(pg, ct):
 
 
 def _rev_fwd_mesh(pg, mesh, axis, el, er):
-    from jax.experimental.shard_map import shard_map
     S, rows, eb = pg.n_shards, pg.rows, pg.eb
     feat = el.shape[1:]
     els = el.reshape((S, rows) + feat)
@@ -686,7 +692,7 @@ def _rev_fwd_mesh(pg, mesh, axis, el, er):
         block = elb[0]
         erloc = erb[0]
         sl, dl, mk = sl[0], dl[0], mk[0]
-        acc = _maybe_pvary(jnp.zeros((S, eb) + feat, el.dtype), axis)
+        acc = pvary(jnp.zeros((S, eb) + feat, el.dtype), axis)
 
         def stage(s, carry):
             acc, block = carry
@@ -714,7 +720,6 @@ def _rev_fwd_mesh(pg, mesh, axis, el, er):
 
 
 def _rev_bwd_mesh(pg, mesh, axis, ct):
-    from jax.experimental.shard_map import shard_map
     S, rows, eb = pg.n_shards, pg.rows, pg.eb
     dtype = _acc_dtype(ct.dtype)
     feat = ct.shape[3:]
@@ -733,7 +738,7 @@ def _rev_bwd_mesh(pg, mesh, axis, ct):
                                  mk[j], None, d_er)
         # ∂el: transposed ring — dst rows rotate backward, each device
         # scatters the bucket whose SOURCES it owns
-        d_el = _maybe_pvary(jnp.zeros((rows,) + feat, dtype), axis)
+        d_el = pvary(jnp.zeros((rows,) + feat, dtype), axis)
 
         def stage(s, carry):
             d_el, block = carry
@@ -778,6 +783,8 @@ def ring_edge_values(pg: PartitionedGraph, el: jnp.ndarray,
         f.defvjp(lambda el, er: (_rev_fwd_emu(pg, el, er), None),
                  lambda res, ct: _rev_bwd_emu(pg, ct))
         return f(el, er)
+
+    require_auto_mesh(mesh)
 
     @jax.custom_vjp
     def f(el, er):

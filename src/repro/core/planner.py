@@ -41,6 +41,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..compat import trace_state_clean
 from .graph import Graph
 from .tiling import (ELLClass, ELLPack, TilePack, build_ell,
                      build_ell_ragged, build_ell_uniform, build_tiles)
@@ -111,6 +112,11 @@ class GraphStats:
     ragged_padded_slots: int = 0
     ragged_n_classes: int = 0
     ragged_pad_ratio: float = 1.0
+    # edge slots of the (M, K)-tile bucket pack at the default geometry
+    # (build_tiles): buckets hold one tile pair each, so a sparse graph
+    # pads most of every bucket. 0 = not counted (block_stats, hand-built
+    # stats): onehot/pallas cannot be priced without it.
+    tile_slots: int = 0
 
 
 def _ell_padding(deg: np.ndarray, cap: int) -> Tuple[int, int]:
@@ -132,6 +138,21 @@ def _ell_padding(deg: np.ndarray, cap: int) -> Tuple[int, int]:
     if full.any():
         widths.add(cap)
     return padded, len(widths)
+
+
+def tile_pack_slots(g: Graph, bm: int = _DEFAULT_TILE_GEOM[0],
+                    bk: int = _DEFAULT_TILE_GEOM[1],
+                    eb: int = _DEFAULT_TILE_GEOM[2]) -> int:
+    """Edge slots :func:`~repro.core.tiling.build_tiles` allocates: one
+    ``eb``-slot bucket per started run of ``eb`` edges of each occupied
+    (dst-tile, src-tile) pair. Counted without building the pack."""
+    if g.n_edges == 0:
+        return eb
+    n_tiles_k = max(1, -(-g.n_src // bk))
+    key = (np.asarray(g.dst, np.int64) // bm) * n_tiles_k \
+        + np.asarray(g.src, np.int64) // bk
+    _, counts = np.unique(key, return_counts=True)
+    return int((-(-counts // eb)).sum()) * eb
 
 
 def ell_rowcomplete_padding(deg) -> Tuple[int, int]:
@@ -167,7 +188,8 @@ def compute_stats(g: Graph, ell_cap: int = _DEFAULT_ELL_CAP) -> GraphStats:
         ell_padded_slots=int(padded), ell_n_classes=int(n_cls),
         pad_ratio=float(padded / max(n_edges, 1)),
         ragged_padded_slots=int(rslots), ragged_n_classes=int(rcls),
-        ragged_pad_ratio=float(rslots / max(n_edges, 1)))
+        ragged_pad_ratio=float(rslots / max(n_edges, 1)),
+        tile_slots=tile_pack_slots(g))
 
 
 # --------------------------------------------------------------------- #
@@ -382,7 +404,7 @@ class PlanCache:
         if self._krel is not None and self._krel.n_rel == int(n_rel):
             return self._krel
         g = self._graph()
-        if g is None or not jax.core.trace_state_clean():
+        if g is None or not trace_state_clean():
             # never build under an active trace — np→jnp conversions
             # there leak trace-bound arrays into the process-wide cache
             return None
@@ -445,21 +467,23 @@ _THROUGHPUT = {
 _FIXED = {"push": 0.0, "segment": 0.0, "ell": 2e4,
           "onehot": 5e4, "pallas": 5e4, "ring": 1e5}
 _ELL_CLASS_OVERHEAD = 1.5e3     # per degree class: one segment combine
-_TILE_EDGE_BUDGET = 256         # eb — edge slots per tile bucket
 _RING_COMM = 0.3   # per fp32-equivalent element moved per ring stage
 _RING_DEFAULT_SHARDS = 8        # nominal S when no ring context is live
 
 
 def _throughput_row(backend: Optional[str], dtype) -> Dict[str, float]:
-    """Backend throughput row, refined by element dtype when a
-    half-precision row exists (``"<backend>:bf16"``)."""
+    """Backend throughput row, the ``"<backend>:bf16"`` row for bf16
+    operands. A backend without a row is an error: pricing it with
+    another device's numbers would pick plans for the wrong machine."""
     backend = backend or jax.default_backend()
+    key = backend
     if dtype is not None and jnp.dtype(dtype) == jnp.dtype(jnp.bfloat16):
-        row = _THROUGHPUT.get(f"{backend}:bf16")
-        if row is None:
-            row = _THROUGHPUT.get("cpu:bf16")
-        return row
-    return _THROUGHPUT.get(backend, _THROUGHPUT["cpu"])
+        key = f"{backend}:bf16"
+    row = _THROUGHPUT.get(key)
+    if row is None:
+        raise ValueError(f"the planner has no cost row for backend "
+                         f"{key!r}; known rows: {sorted(_THROUGHPUT)}")
+    return row
 
 
 def estimate_cost(strategy: str, stats: GraphStats, d: int,
@@ -519,9 +543,15 @@ def estimate_cost(strategy: str, stats: GraphStats, d: int,
         # stage count, not S-1.
         comm_cost = _RING_COMM * stages * (wire / 4.0)
         return tp * work + comm_cost + _FIXED[strategy]
-    else:  # onehot / pallas: padded tile-bucket slots (lower bound on T)
-        n_buckets = max(1, -(-stats.n_edges // _TILE_EDGE_BUDGET))
-        work = n_buckets * _TILE_EDGE_BUDGET * dd
+    else:  # onehot / pallas: the tile pack's padded bucket slots
+        slots = stats.tile_slots
+        if slots <= 0:
+            raise ValueError(f"pricing {strategy!r} needs the tile pack's "
+                             f"slot count (GraphStats.tile_slots, set by "
+                             f"compute_stats)")
+        if strategy == "pallas":
+            dd = -(-dd // 128) * 128      # the kernel's 128-lane tiles
+        work = slots * dd
     cost = tp * work + _FIXED[strategy]
     if strategy == "ell":
         cost += _ELL_CLASS_OVERHEAD * stats.ell_n_classes
@@ -564,6 +594,8 @@ def use_ring(mesh, axis: str = "data", mode: str = "contiguous",
     never qualifies: ``strategy="auto"`` plans single-device and a
     pinned ``"ring"`` falls back down the established chain.
     """
+    from .partition import require_auto_mesh  # local: avoids cycle
+    require_auto_mesh(mesh)
     global _RING_CTX
     prev = _RING_CTX
     _RING_CTX = RingContext(mesh=mesh, axis=axis, mode=mode, comm=comm)
@@ -991,7 +1023,7 @@ def _block_bwd_cost(strategy: str, signature: Tuple[int, int, int, int],
                     d: int, backend: str) -> float:
     """Estimated cost of differentiating one block op (element-ops)."""
     n_src, _, slots, _ = signature
-    tp = _THROUGHPUT.get(backend, _THROUGHPUT["cpu"])
+    tp = _throughput_row(backend, None)
     dd = max(int(d), 1)
     if strategy == "gather":
         return tp["segment"] * (1.0 + _BWD_GATHER_REORDER) * slots * dd
@@ -1107,7 +1139,7 @@ def _hetero_cost(strategy: str, signature: Tuple[int, int, int, int],
     """Estimated cost of one relational aggregation (element-ops);
     None when the strategy has no model (ell without fused stats)."""
     _, _, n_edges, n_rel = signature
-    tp = _THROUGHPUT.get(backend, _THROUGHPUT["cpu"])
+    tp = _throughput_row(backend, None)
     dd = max(int(d), 1)
     if strategy == "loop":
         return (tp["segment"] * n_edges * dd
@@ -1259,7 +1291,7 @@ def sddmm_supports(strategy: str, spec, lhs_data, rhs_data) -> bool:
 
 
 def _sddmm_cost(strategy: str, n_edges: int, d: int, backend: str) -> float:
-    tp = _THROUGHPUT.get(backend, _THROUGHPUT["cpu"])
+    tp = _throughput_row(backend, None)
     work = n_edges * max(int(d), 1)
     if strategy == "canonical":
         tax = _SDDMM_GATHER_TAX if backend == "cpu" else 1.0
@@ -1355,7 +1387,7 @@ def _attn_cost(strategy: str, n_edges: int, hf: int, backend: str,
                padded_slots: Optional[int] = None) -> Optional[float]:
     """Estimated cost of one fused-attention pass (element-ops); None
     for ring (the partitioned composition has no single-device model)."""
-    tp = _THROUGHPUT.get(backend, _THROUGHPUT["cpu"])
+    tp = _throughput_row(backend, None)
     if strategy == "fused":
         return tp["segment"] * n_edges * hf
     if strategy == "pallas":

@@ -17,7 +17,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ..common import onehot_gather_matrix, onehot_scatter_matrix
+from ..common import (gather_rows, onehot_gather_matrix_t,
+                      onehot_scatter_matrix)
 
 _BINOPS = {
     "add": jnp.add,
@@ -38,17 +39,17 @@ def _br_kernel(tile_m_ref, tile_k_ref, first_ref,
     def _init():
         out_ref[...] = jnp.zeros_like(out_ref)
 
-    dst_local = dst_ref[0]
-    src_local = src_ref[0]
-    mask = mask_ref[0] != 0
+    dst_local = dst_ref[...]                                # (1, eb)
+    src_local = src_ref[...]
+    mask = mask_ref[...] != 0
     acc_t = jnp.float32
 
-    G = onehot_gather_matrix(src_local, mask, bk, b_ref.dtype)
-    u_vals = jax.lax.dot(G, b_ref[...], preferred_element_type=acc_t)
+    GT = onehot_gather_matrix_t(src_local, mask, bk, b_ref.dtype)
+    u_vals = gather_rows(GT, b_ref[...])                    # (eb, nd)
     e_vals = e_ref[...].astype(acc_t)                       # (eb, nd)
+    # padded slots gather u = 0 and carry e = 1 (ops.py), so every ⊗
+    # stays finite there and the zero scatter column drops them
     msg = _BINOPS[binop](u_vals, e_vals)
-    # padded slots may hold 0/0 etc. — zero them before the scatter matmul
-    msg = jnp.where(mask[:, None], msg, jnp.zeros((), msg.dtype))
     S = onehot_scatter_matrix(dst_local, mask, bm, msg.dtype)
     out_ref[...] += jax.lax.dot(S, msg, preferred_element_type=acc_t
                                 ).astype(out_ref.dtype)
@@ -58,12 +59,13 @@ def binary_reduce_pallas_call(T: int, eb: int, bm: int, bk: int, nd: int,
                               n_tiles_m: int, n_tiles_k: int, d_pad: int,
                               dtype, *, binop: str, interpret: bool):
     """Inputs: tile_m, tile_k, first (scalar prefetch); dst_local,
-    src_local, mask (T,eb) int32; E_tiles (T*eb, d_pad) tile-ordered edge
-    features; B (n_tiles_k*bk, d_pad). Output: C (n_tiles_m*bm, d_pad)."""
+    src_local, mask (T,1,eb) int32; E_tiles (T*eb, d_pad) tile-ordered
+    edge features, 1 in padded slots; B (n_tiles_k*bk, d_pad).
+    Output: C (n_tiles_m*bm, d_pad)."""
     n_nd = d_pad // nd
     grid = (n_nd, T)
 
-    edge_map = lambda n, t, tm, tk, first: (t, 0)
+    edge_map = lambda n, t, tm, tk, first: (t, 0, 0)
     e_map = lambda n, t, tm, tk, first: (t, n)
     b_map = lambda n, t, tm, tk, first: (tk[t], n)
     out_map = lambda n, t, tm, tk, first: (tm[t], n)
@@ -72,9 +74,9 @@ def binary_reduce_pallas_call(T: int, eb: int, bm: int, bk: int, nd: int,
         num_scalar_prefetch=3,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1, eb), edge_map),
-            pl.BlockSpec((1, eb), edge_map),
-            pl.BlockSpec((1, eb), edge_map),
+            pl.BlockSpec((None, 1, eb), edge_map),
+            pl.BlockSpec((None, 1, eb), edge_map),
+            pl.BlockSpec((None, 1, eb), edge_map),
             pl.BlockSpec((eb, nd), e_map),
             pl.BlockSpec((bk, nd), b_map),
         ],

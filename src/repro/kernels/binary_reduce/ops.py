@@ -10,7 +10,7 @@ import jax.numpy as jnp
 from ...core.graph import Graph
 from ...core.planner import get_plan_cache
 from ...core.tiling import TilePack
-from ..common import should_interpret
+from ..common import should_interpret, visited_rows
 from .kernel import binary_reduce_pallas_call
 
 
@@ -31,6 +31,9 @@ def _br_packed(pack: TilePack, B: jnp.ndarray, E_tiles: jnp.ndarray,
     d_pad = _round_up(d, nd)
 
     Bp = jnp.pad(B, ((0, pack.n_tiles_k * bk - B.shape[0]), (0, d_pad - d)))
+    # padded slots carry 1 so every ⊗ (div included) stays finite there
+    E_tiles = jnp.where(pack.mask.reshape(-1, 1), E_tiles,
+                        jnp.ones((), E_tiles.dtype))
     Ep = jnp.pad(E_tiles, ((0, 0), (0, d_pad - d)))
 
     call = binary_reduce_pallas_call(
@@ -39,10 +42,11 @@ def _br_packed(pack: TilePack, B: jnp.ndarray, E_tiles: jnp.ndarray,
         dtype=Bp.dtype, binop=binop,
         interpret=should_interpret() if interpret is None else interpret)
 
+    rows = lambda a: a.reshape(T, 1, eb)
     out = call(pack.tile_m, pack.tile_k, pack.first_of_m,
-               pack.dst_local, pack.src_local,
-               pack.mask.astype(jnp.int32), Ep, Bp)
-    out = out[: pack.n_dst, :d]
+               rows(pack.dst_local), rows(pack.src_local),
+               rows(pack.mask.astype(jnp.int32)), Ep, Bp)
+    out = visited_rows(out, pack)[: pack.n_dst, :d]
     if reduce_op == "mean":
         out = out / jnp.maximum(deg, 1).astype(out.dtype)[:, None]
     return out

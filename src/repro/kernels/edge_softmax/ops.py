@@ -1,4 +1,5 @@
-"""Jitted public wrapper for the fused edge-softmax Pallas kernel."""
+"""Jitted public wrappers for the edge-softmax and fused-attention
+Pallas kernels."""
 from __future__ import annotations
 
 import functools
@@ -12,6 +13,8 @@ from ...core.graph import Graph
 from ...core.planner import get_plan_cache
 from ...core.tiling import ELLClass, ELLPack
 from ..common import should_interpret
+from ..vmem import (LANES, VMEM_BYTES, edge_softmax_vmem_bytes,
+                    fused_attention_vmem_bytes)
 from .kernel import edge_softmax_pallas_call, fused_attention_pallas_call
 
 
@@ -19,21 +22,32 @@ def _round_up(x: int, m: int) -> int:
     return ((x + m - 1) // m) * m
 
 
-# Per-grid-step stripe element budget for the ragged per-class launches
-# (~64 MB of fp32). Narrow classes take huge row blocks (often the whole
-# class in one step), wide hub classes take small ones — so the grid
-# stays shallow everywhere, which is what keeps the interpreted CPU
-# lowering (one Python dispatch per grid step) fast.
-_RAGGED_BLOCK_ELEMS = 1 << 24
+# Block geometry from the VMEM model (kernels/vmem.py): a kernel's
+# double-buffered blocks, scratch and working values, counted in
+# (8, 128) tiles, stay under half of v5e's 16 MiB scoped VMEM. The fused
+# attention puts destination rows on the lanes, so a row block is a
+# multiple of 128 rows; a stripe wider than ``_WB_MAX`` streams through
+# the online softmax in blocks of ``_WB_MAX`` slots, which bounds the
+# footprint of hub rows of any degree. ``_BR_MAX`` caps the row block
+# so the running (H, F, br) accumulator stays a few dozen vregs.
+_VMEM_BUDGET = VMEM_BYTES // 2
+_WB_MAX = 32
+_BR_MAX = 512
 
 
-def _ragged_br(C: int, W: int, H: int, F: int) -> int:
-    """Adaptive row-block size for one ragged class: as many rows per
-    grid step as the element budget allows, multiple of 8, ≥ 8, and no
-    larger than the class itself padded to 8."""
-    per_row = max(W * H * max(F, 1), 1)
-    b = min(_RAGGED_BLOCK_ELEMS // per_row, _round_up(max(C, 1), 8))
-    return max((b // 8) * 8, 8)
+def _attention_blocks(C: int, W: int, H: int, F: int):
+    """(br, wb) for one row-complete stripe class of C rows of width W:
+    ``wb`` is W or ``_WB_MAX``; ``br`` the largest multiple of 128 rows
+    that fits the VMEM budget, no larger than the class needs."""
+    wb = W if W <= _WB_MAX else _WB_MAX
+    per_block = fused_attention_vmem_bytes(LANES, wb, H, F)
+    fit = (_VMEM_BUDGET // per_block) * LANES
+    if fit < LANES:
+        raise ValueError(
+            f"fused attention with H={H}, F={F} needs {per_block} bytes "
+            f"of VMEM per 128-row block, over the {_VMEM_BUDGET}-byte "
+            f"budget")
+    return min(fit, _BR_MAX, _round_up(max(C, 1), LANES)), wb
 
 
 @functools.partial(jax.jit,
@@ -46,26 +60,34 @@ def _edge_softmax_packed(pack: ELLClass, logits: jnp.ndarray,
     C, W = pack.chunk_cols.shape
     H = logits.shape[-1]
     C_pad = _round_up(C, br)
+    need = edge_softmax_vmem_bytes(br, W, H)
+    if need > _VMEM_BUDGET:
+        raise ValueError(f"edge_softmax block (H={H}, br={br}, W={W}) "
+                         f"needs {need} bytes of VMEM, over the "
+                         f"{_VMEM_BUDGET}-byte budget: lower br")
 
-    # gather logits (caller order) into the padded ELL stripes
-    x = jnp.take(logits, pack.chunk_eids, axis=0)          # (C, W, H)
-    x = jnp.pad(x, ((0, C_pad - C), (0, 0), (0, 0)))
+    # gather logits (caller order) into head-major padded ELL stripes
+    x = jnp.take(logits.T, pack.chunk_eids, axis=1)        # (H, C, W)
+    x = jnp.pad(x, ((0, 0), (0, C_pad - C), (0, 0)))
     mask = jnp.pad(pack.chunk_mask.astype(jnp.int32),
-                   ((0, C_pad - C), (0, 0)))
+                   ((0, C_pad - C), (0, 0)))[None]
 
     call = edge_softmax_pallas_call(
         C_pad, W, H, br, logits.dtype,
         interpret=should_interpret() if interpret is None else interpret)
-    out = call(x, mask)                                    # (C_pad, W, H)
+    out = call(x, mask)                                    # (H, C_pad, W)
 
-    # scatter back to caller edge order: every real edge occupies exactly
-    # one (chunk, w) slot, so a masked set is a pure permutation.
-    flat_vals = out[:C].reshape(C * W, H)
+    # back to caller edge order: every real edge occupies exactly one
+    # (chunk, w) slot, so this is a pure permutation — find each edge's
+    # slot with a 1-D scatter, then gather its row (a scatter of whole
+    # H-wide rows compiles for seconds on a TPU)
+    flat_vals = out[:, :C].reshape(H, C * W).T
     flat_eids = pack.chunk_eids.reshape(C * W)
     flat_mask = pack.chunk_mask.reshape(C * W)
     safe_ids = jnp.where(flat_mask, flat_eids, n_edges)    # drop pads
-    res = jnp.zeros((n_edges, H), out.dtype)
-    return res.at[safe_ids].set(flat_vals, mode="drop")
+    slot = jnp.zeros((n_edges,), jnp.int32).at[safe_ids].set(
+        jnp.arange(C * W, dtype=jnp.int32), mode="drop")
+    return jnp.take(flat_vals, slot, axis=0)
 
 
 def edge_softmax(g: Graph, logits: jnp.ndarray,
@@ -91,35 +113,41 @@ def edge_softmax(g: Graph, logits: jnp.ndarray,
     return out[:, 0] if squeeze else out
 
 
-@functools.partial(jax.jit,
-                   static_argnames=("n_dst", "slope", "br", "interpret"))
-def _fused_attention_packed(pack: ELLClass, el: jnp.ndarray,
-                            er: jnp.ndarray, z: jnp.ndarray, n_dst: int,
-                            slope: float, br: int,
-                            interpret: bool) -> jnp.ndarray:
-    """Attention megakernel over row-complete stripes → (n_dst, H, F)."""
-    C, W = pack.chunk_cols.shape
+def _attention_stripes(cls: ELLClass, el: jnp.ndarray, er: jnp.ndarray,
+                       z: jnp.ndarray, slope: float,
+                       interpret: bool) -> jnp.ndarray:
+    """One row-complete stripe class through the megakernel → (C, H, F)
+    rows in the class's chunk order, laid out rows-on-lanes."""
+    C, W = cls.chunk_cols.shape
     H = el.shape[-1]
     F = z.shape[-1]
+    br, wb = _attention_blocks(C, W, H, F)
     C_pad = _round_up(max(C, 1), br)
-
-    el_t = jnp.take(el, pack.chunk_cols, axis=0)           # (C, W, H)
-    er_t = jnp.take(er, pack.chunk_row, axis=0)            # (C, H)
-    z_t = jnp.take(z, pack.chunk_cols, axis=0)             # (C, W, H, F)
-    el_t = jnp.pad(el_t, ((0, C_pad - C), (0, 0), (0, 0)))
-    er_t = jnp.pad(er_t, ((0, C_pad - C), (0, 0)))
-    z_t = jnp.pad(z_t, ((0, C_pad - C), (0, 0), (0, 0), (0, 0)))
-    mask = jnp.pad(pack.chunk_mask.astype(jnp.int32),
-                   ((0, C_pad - C), (0, 0)))
-
-    call = fused_attention_pallas_call(C_pad, W, H, F, br, z.dtype,
+    W_pad = _round_up(W, wb)
+    pad = ((0, W_pad - W), (0, C_pad - C))
+    cols = jnp.pad(cls.chunk_cols.T, pad)                  # (W_pad, C_pad)
+    mask = jnp.pad(cls.chunk_mask.T.astype(jnp.int32), pad)[None]
+    el_t = jnp.take(el.T, cols, axis=1)                    # (H, W_pad, C_pad)
+    er_t = jnp.pad(jnp.take(er.T, cls.chunk_row, axis=1),
+                   ((0, 0), (0, C_pad - C)))[:, None]      # (H, 1, C_pad)
+    z_t = jnp.take(z, cols, axis=0).transpose(2, 0, 3, 1)  # (H, W_pad, F, C_pad)
+    call = fused_attention_pallas_call(C_pad, W_pad, H, F, br, wb, z.dtype,
                                        slope=slope, interpret=interpret)
-    out = call(el_t, er_t, z_t, mask)                      # (C_pad, H, F)
+    out = call(el_t, er_t, z_t, mask)                      # (H, F, C_pad)
+    return out[:, :, :C].transpose(2, 0, 1)
 
+
+@functools.partial(jax.jit,
+                   static_argnames=("n_dst", "slope", "interpret"))
+def _fused_attention_packed(pack: ELLClass, el: jnp.ndarray,
+                            er: jnp.ndarray, z: jnp.ndarray, n_dst: int,
+                            slope: float, interpret: bool) -> jnp.ndarray:
+    """Attention megakernel over row-complete stripes → (n_dst, H, F)."""
+    out = _attention_stripes(pack, el, er, z, slope, interpret)
     # row-complete pack: each chunk is one whole destination row, so the
     # scatter-back is a pure permutation; zero-degree rows stay 0 (DGL)
-    res = jnp.zeros((n_dst, H, F), out.dtype)
-    return res.at[pack.chunk_row].set(out[:C])
+    res = jnp.zeros((n_dst,) + out.shape[1:], out.dtype)
+    return res.at[pack.chunk_row].set(out)
 
 
 @functools.partial(jax.jit,
@@ -136,33 +164,16 @@ def _fused_attention_ragged(pack: ELLPack, el: jnp.ndarray,
     row), so the per-class scatter-back is a pure permutation and the
     class outputs never overlap.
     """
-    H = el.shape[-1]
-    F = z.shape[-1]
-    res = jnp.zeros((pack.n_dst, H, F), z.dtype)
+    res = jnp.zeros((pack.n_dst, el.shape[-1], z.shape[-1]), z.dtype)
     for cls in pack.classes:
-        C, W = cls.chunk_cols.shape
-        b = _ragged_br(C, W, H, F)
-        C_pad = _round_up(max(C, 1), b)
-        el_t = jnp.take(el, cls.chunk_cols, axis=0)        # (C, W, H)
-        er_t = jnp.take(er, cls.chunk_row, axis=0)         # (C, H)
-        z_t = jnp.take(z, cls.chunk_cols, axis=0)          # (C, W, H, F)
-        el_t = jnp.pad(el_t, ((0, C_pad - C), (0, 0), (0, 0)))
-        er_t = jnp.pad(er_t, ((0, C_pad - C), (0, 0)))
-        z_t = jnp.pad(z_t, ((0, C_pad - C), (0, 0), (0, 0), (0, 0)))
-        mask = jnp.pad(cls.chunk_mask.astype(jnp.int32),
-                       ((0, C_pad - C), (0, 0)))
-        call = fused_attention_pallas_call(C_pad, W, H, F, b, z.dtype,
-                                           slope=slope,
-                                           interpret=interpret)
-        out = call(el_t, er_t, z_t, mask)                  # (C_pad, H, F)
-        res = res.at[cls.chunk_row].set(out[:C])
+        out = _attention_stripes(cls, el, er, z, slope, interpret)
+        res = res.at[cls.chunk_row].set(out)
     return res
 
 
 def fused_attention(g: Graph, el: jnp.ndarray, er: jnp.ndarray,
                     z: jnp.ndarray, slope: float = 0.2,
                     ell: Optional[Union[ELLClass, ELLPack]] = None,
-                    br: int = 8,
                     interpret: Optional[bool] = None) -> jnp.ndarray:
     """GAT attention pipeline as ONE kernel pass.
 
@@ -198,5 +209,5 @@ def fused_attention(g: Graph, el: jnp.ndarray, er: jnp.ndarray,
         raise ValueError("pack splits rows; fused_attention needs "
                          "width >= max in-degree")
     return _fused_attention_packed(
-        ell, el, er, z, g.n_dst, float(slope), br,
+        ell, el, er, z, g.n_dst, float(slope),
         should_interpret() if interpret is None else interpret)

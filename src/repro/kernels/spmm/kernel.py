@@ -9,9 +9,14 @@ VMEM; it is written back to HBM exactly once per feature tile (the paper's
 Per grid step:
   * ``BlockSpec`` DMAs the K-block of source features ``B[tile_k]``
     (bk × nd) into VMEM — the paper's "B block stays in L2";
-  * bucket edge indices (eb) arrive as int32 VMEM blocks;
+  * bucket edge indices arrive as ``(1, eb)`` int32 VMEM rows — the
+    streams are stored ``(T, 1, eb)`` so a block's last two dimensions
+    equal the array's, which the TPU lowering requires of blocks that
+    are not (8, 128)-aligned;
   * gather/scatter run as one-hot matmuls on the MXU (DESIGN.md §2) —
-    the TPU replacement for sorted scalar streams.
+    the TPU replacement for sorted scalar streams. Both one-hots are
+    built from the index rows by a sublane broadcast: the gather matrix
+    as its transpose ``G^T`` (bk, eb), contracted on its leading axis.
 
 Reductions: sum (optionally edge-weighted). Mean is sum + a post-scale in
 ``ops.py``. Max/min intentionally stay on the segment path (see DESIGN.md).
@@ -25,7 +30,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ..common import onehot_gather_matrix, onehot_scatter_matrix
+from ..common import (gather_rows, onehot_gather_matrix_t,
+                      onehot_scatter_matrix)
 
 
 def _spmm_kernel(# scalar-prefetch refs
@@ -40,15 +46,14 @@ def _spmm_kernel(# scalar-prefetch refs
     def _init():
         out_ref[...] = jnp.zeros_like(out_ref)
 
-    dst_local = dst_ref[0]          # (eb,) int32
-    src_local = src_ref[0]
-    mask = mask_ref[0] != 0         # int32 block -> bool
+    dst_local = dst_ref[...]        # (1, eb) int32
+    src_local = src_ref[...]
+    mask = mask_ref[...] != 0       # int32 block -> bool
     acc_t = jnp.float32
 
-    G = onehot_gather_matrix(src_local, mask, bk, b_ref.dtype)
-    gathered = jax.lax.dot(G, b_ref[...],
-                           preferred_element_type=acc_t)     # (eb, nd)
-    w = wgt_ref[0] if weighted else None
+    GT = onehot_gather_matrix_t(src_local, mask, bk, b_ref.dtype)
+    gathered = gather_rows(GT, b_ref[...])                   # (eb, nd)
+    w = wgt_ref[...] if weighted else None
     S = onehot_scatter_matrix(dst_local, mask, bm, gathered.dtype, weight=w)
     out_ref[...] += jax.lax.dot(S, gathered,
                                 preferred_element_type=acc_t
@@ -61,8 +66,8 @@ def spmm_pallas_call(T: int, eb: int, bm: int, bk: int, nd: int,
     """Build the pallas_call for given static geometry.
 
     Inputs (in order): tile_m (T,), tile_k (T,), first_of_m (T,)  [scalar
-    prefetch]; dst_local (T,eb), src_local (T,eb), mask (T,eb) int32,
-    weight (T,eb), B (n_tiles_k*bk, d_pad).
+    prefetch]; dst_local (T,1,eb), src_local (T,1,eb), mask (T,1,eb)
+    int32, weight (T,1,eb), B (n_tiles_k*bk, d_pad).
     Output: C (n_tiles_m*bm, d_pad).
     """
     n_nd = d_pad // nd
@@ -70,7 +75,7 @@ def spmm_pallas_call(T: int, eb: int, bm: int, bk: int, nd: int,
     grid = (n_nd, T)
 
     def edge_map(n, t, tm, tk, first):
-        return (t, 0)
+        return (t, 0, 0)
 
     def b_map(n, t, tm, tk, first):
         return (tk[t], n)
@@ -82,10 +87,10 @@ def spmm_pallas_call(T: int, eb: int, bm: int, bk: int, nd: int,
         num_scalar_prefetch=3,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1, eb), edge_map),   # dst_local
-            pl.BlockSpec((1, eb), edge_map),   # src_local
-            pl.BlockSpec((1, eb), edge_map),   # mask
-            pl.BlockSpec((1, eb), edge_map),   # weight
+            pl.BlockSpec((None, 1, eb), edge_map),   # dst_local
+            pl.BlockSpec((None, 1, eb), edge_map),   # src_local
+            pl.BlockSpec((None, 1, eb), edge_map),   # mask
+            pl.BlockSpec((None, 1, eb), edge_map),   # weight
             pl.BlockSpec((bk, nd), b_map),     # B k-block
         ],
         out_specs=pl.BlockSpec((bm, nd), out_map),

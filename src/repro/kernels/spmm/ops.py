@@ -10,7 +10,7 @@ import jax.numpy as jnp
 from ...core.graph import Graph
 from ...core.planner import get_plan_cache
 from ...core.tiling import TilePack
-from ..common import should_interpret
+from ..common import should_interpret, visited_rows
 from .kernel import spmm_pallas_call
 
 
@@ -40,10 +40,12 @@ def _spmm_packed(pack: TilePack, B: jnp.ndarray,
         dtype=Bp.dtype, weighted=weighted,
         interpret=should_interpret() if interpret is None else interpret)
 
+    rows = lambda a: a.reshape(T, 1, eb)
     out = call(pack.tile_m, pack.tile_k, pack.first_of_m,
-               pack.dst_local, pack.src_local,
-               pack.mask.astype(jnp.int32), w.astype(Bp.dtype), Bp)
-    out = out[: pack.n_dst, :d]
+               rows(pack.dst_local), rows(pack.src_local),
+               rows(pack.mask.astype(jnp.int32)), rows(w.astype(Bp.dtype)),
+               Bp)
+    out = visited_rows(out, pack)[: pack.n_dst, :d]
     if reduce_op == "mean":
         out = out / jnp.maximum(deg, 1).astype(out.dtype)[:, None]
     return out

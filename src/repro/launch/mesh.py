@@ -28,7 +28,7 @@ def make_shard_mesh(n_shards: int, axis: str = "data") -> Mesh:
     host the devices are emulated — set
     ``XLA_FLAGS=--xla_force_host_platform_device_count=<n>`` BEFORE
     importing jax (tests and benchmarks re-exec a subprocess to do
-    this; see tests/conftest.run_multidevice). With too few devices,
+    this; see tests/conftest.run_multidevice). With too few CPU devices,
     :func:`make_mesh`'s error spells out that exact flag.
     """
     return make_mesh((n_shards,), (axis,))
@@ -40,9 +40,12 @@ def make_mesh(shape: Tuple[int, ...], axes: Tuple[str, ...]) -> Mesh:
     n = int(np.prod(shape))
     devices = jax.devices()
     if len(devices) < n:
-        raise RuntimeError(
-            f"mesh {shape} needs {n} devices, have {len(devices)} — "
-            f"set XLA_FLAGS=--xla_force_host_platform_device_count={n} "
-            f"BEFORE importing jax (see launch/dryrun.py)")
+        msg = f"mesh {shape} needs {n} devices, have {len(devices)}"
+        if devices[0].platform == "cpu":
+            # host devices can be emulated; chips cannot
+            flag = f"--xla_force_host_platform_device_count={n}"
+            msg += (f" — set XLA_FLAGS={flag} BEFORE importing jax "
+                    f"(see launch/dryrun.py)")
+        raise RuntimeError(msg)
     arr = np.asarray(devices[:n]).reshape(shape)
     return Mesh(arr, axes)

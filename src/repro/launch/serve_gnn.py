@@ -25,6 +25,7 @@ from ..core import planner
 from ..core.serving import SERVE_APPS, GNNServer
 from ..data import RequestQueue, make_node_dataset, relational_graph
 from ..models.gnn import gat, gcn, rgcn, sage
+from .compile_cache import setup_compile_cache
 from ..obs import (export_chrome_trace, percentile_nearest_rank, snapshot,
                    span_coverage)
 
@@ -146,6 +147,7 @@ def main():
                     help="print the planner predicted-vs-measured "
                          "drift report after the session")
     args = ap.parse_args()
+    setup_compile_cache()
 
     srv = build_server(args.app, args.dataset, mode=args.mode,
                        classes=tuple(args.classes), fanout=args.fanout,

@@ -24,6 +24,7 @@ import time
 
 import jax
 
+from ..compat import trace_state_clean
 from . import metrics as _metrics
 from .metrics import enabled
 
@@ -108,7 +109,7 @@ def timed(op, thunk):
     eagerly (not under a jit/vjp trace, where timing would measure
     tracing instead of execution), fence the result and record the wall
     time as a measured event for ``op``. Returns the thunk's result."""
-    if not enabled() or not jax.core.trace_state_clean():
+    if not enabled() or not trace_state_clean():
         return thunk()
     t0 = time.perf_counter()
     out = thunk()

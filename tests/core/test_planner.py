@@ -13,6 +13,8 @@ import pytest
 from repro.core import (from_coo, gspmm, copy_reduce, edge_softmax,
                         parse_op, planner)
 
+from ..conftest import make_graph
+
 # the exact configurations from the paper's Table 2
 TABLE2 = [
     "u_copy_add_v",        # GCN/SAGE/GCMC/LGNN/RGCN
@@ -245,9 +247,10 @@ def test_stats_and_cost_model_sanity():
     assert stats.ell_padded_slots >= stats.n_edges
     assert stats.pad_ratio >= 1.0
     # every strategy costs something, and costs grow with feature width
+    # (across a 128-lane tile: the pallas kernels pad features to 128)
     for s in planner.STRATEGIES:
         assert planner.estimate_cost(s, stats, 8) > 0
-        assert (planner.estimate_cost(s, stats, 128)
+        assert (planner.estimate_cost(s, stats, 256)
                 > planner.estimate_cost(s, stats, 8))
 
 
@@ -268,3 +271,36 @@ def test_supports_predicates():
     assert not planner.supports("pallas", specmax, x, None)
     assert not planner.supports("onehot", specmax, x, None)
     assert planner.supports("ell", specmax, x, None)
+
+
+def test_unknown_backend_has_no_cost_row():
+    """A backend without a throughput row is an error, never priced
+    with another device's numbers (bf16 included)."""
+    with pytest.raises(ValueError, match="no cost row"):
+        planner._throughput_row("gpu", None)
+    with pytest.raises(ValueError, match="no cost row"):
+        planner._throughput_row("gpu", jnp.bfloat16)
+    assert planner._throughput_row("tpu", jnp.bfloat16) is \
+        planner._THROUGHPUT["tpu:bf16"]
+
+
+def test_tile_slots_match_built_pack():
+    """The stats' tile-slot count is exactly what build_tiles allocates,
+    so the pallas/onehot cost rows price the real padded buckets."""
+    from repro.core import build_tiles
+    rng = np.random.default_rng(11)
+    for n_u, n_v, nnz in [(300, 200, 1500), (500, 10, 2000), (5, 7, 1)]:
+        g, _, _ = make_graph(rng, n_u, n_v, nnz)
+        pack = build_tiles(g)
+        assert planner.compute_stats(g).tile_slots == \
+            pack.dst_local.shape[0] * pack.eb
+
+
+@pytest.mark.parametrize("strategy", ["onehot", "pallas"])
+def test_tile_strategies_need_counted_slots(strategy):
+    """Stats without a tile-slot count (block_stats) cannot price the
+    tile kernels: an ideal-fill guess under-prices sparse graphs."""
+    stats = planner.block_stats(1024, 512, 4096, 8)
+    assert stats.tile_slots == 0
+    with pytest.raises(ValueError, match="tile_slots"):
+        planner.estimate_cost(strategy, stats, 64, backend="tpu")

@@ -60,3 +60,15 @@ def test_mean_reduce():
     deg = np.zeros(70); np.add.at(deg, np.asarray(g.dst), 1)
     ref = np.asarray(ref) / np.maximum(deg, 1)[:, None]
     np.testing.assert_allclose(np.asarray(out), ref, rtol=1e-4, atol=1e-4)
+
+
+def test_unvisited_tiles_zero():
+    """Output tiles that no edge bucket visits read 0, not whatever the
+    output buffer held."""
+    from repro.core import from_coo
+    g = from_coo([0, 1, 2], [3, 4, 5], n_src=3, n_dst=400)
+    B = jnp.ones((3, 8), jnp.float32)
+    E = jnp.full((3, 8), 2.0, jnp.float32)
+    out = np.asarray(binary_reduce(g, B, E, binop="div"))
+    np.testing.assert_allclose(out[3:6], 0.5)
+    np.testing.assert_allclose(np.delete(out, [3, 4, 5], axis=0), 0.0)

@@ -70,10 +70,13 @@ def test_spmm_custom_tile_geometry():
                                    err_msg=f"bm={bm} bk={bk} eb={eb}")
 
 
-def test_spmm_empty_rows_zero():
-    """Nodes with no incoming edges must read 0 (DGL semantics)."""
-    g = from_coo([0, 1], [2, 2], n_src=3, n_dst=5)
+@pytest.mark.parametrize("n_dst", [5, 400])
+def test_spmm_empty_rows_zero(n_dst):
+    """Nodes with no incoming edges must read 0 (DGL semantics) — also
+    in 128-row output tiles that no edge bucket visits (n_dst=400)."""
+    g = from_coo([0, 1], [2, 2], n_src=3, n_dst=n_dst)
     B = jnp.ones((3, 8), jnp.float32)
     out = np.asarray(spmm(g, B, "sum"))
     np.testing.assert_allclose(out[2], 2.0)
-    np.testing.assert_allclose(out[[0, 1, 3, 4]], 0.0)
+    rest = np.setdiff1d(np.arange(n_dst), [2])
+    np.testing.assert_allclose(out[rest], 0.0)

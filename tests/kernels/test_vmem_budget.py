@@ -37,3 +37,26 @@ def test_autotuned_geometry_correct():
     ref = spmm_ref(g.src, g.dst, B, 300, "sum")
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                rtol=1e-5, atol=1e-5)
+
+
+def test_tile_bytes_counts_layout_padding():
+    from repro.kernels.vmem import tile_bytes
+    assert tile_bytes(8, 128) == 8 * 128 * 4
+    assert tile_bytes(1, 256) == 8 * 256 * 4          # sublanes pad to 8
+    assert tile_bytes(4, 16, 16) == 4 * 16 * 128 * 4  # lanes pad to 128
+    assert tile_bytes(16, 128, itemsize=2) == 16 * 128 * 2
+
+
+def test_attention_blocks_fit_budget_at_any_width():
+    """Row blocks are whole 128-lane multiples, width blocks divide the
+    padded width, and the double-buffered footprint stays in budget —
+    including hub classes thousands of slots wide."""
+    from repro.kernels.edge_softmax.ops import _attention_blocks
+    from repro.kernels.vmem import fused_attention_vmem_bytes
+    for C, W, H, F in [(30597, 1, 4, 16), (3992, 32, 4, 16),
+                       (1, 8192, 4, 16), (500, 40, 1, 41),
+                       (3, 2, 8, 64)]:
+        br, wb = _attention_blocks(C, W, H, F)
+        assert br % 128 == 0 and br >= 128
+        assert wb == W or (wb % 8 == 0 and wb < W)
+        assert fused_attention_vmem_bytes(br, wb, H, F) <= VMEM_BYTES // 2

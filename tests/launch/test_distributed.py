@@ -17,8 +17,9 @@ from repro.core import from_coo
 from repro.core.partition import (build_partition, ring_gspmm,
                                   ring_reference)
 from repro.kernels.spmm.ref import spmm_ref
+from repro.launch.mesh import make_shard_mesh
 
-mesh = jax.make_mesh((8,), ("data",))
+mesh = make_shard_mesh(8)        # Auto axes: the ring rejects Explicit
 rng = np.random.default_rng(0)
 n, nnz, d = 64, 400, 16
 src = rng.integers(0, n, nnz); dst = rng.integers(0, n, nnz)
@@ -91,3 +92,22 @@ def test_sharded_train_matches_single_device():
     r = run_multidevice(_SHARDED_TRAIN_PROG)
     assert r.returncode == 0, r.stderr[-3000:]
     assert "SHARDED_TRAIN_OK" in r.stdout
+
+
+@pytest.mark.parametrize("platform", ["cpu", "tpu"])
+def test_make_mesh_error_prescribes_emulation_only_on_cpu(monkeypatch,
+                                                          platform):
+    """Too few devices: on the CPU the error names the host-emulation
+    flag; on a chip, where devices cannot be emulated, it must not."""
+    import types
+
+    import jax
+
+    from repro.launch.mesh import make_shard_mesh
+
+    fake = [types.SimpleNamespace(platform=platform)]
+    monkeypatch.setattr(jax, "devices", lambda: fake)
+    with pytest.raises(RuntimeError, match="needs 4 devices, have 1") as e:
+        make_shard_mesh(4)
+    assert ("xla_force_host_platform_device_count" in str(e.value)) == \
+        (platform == "cpu")

@@ -69,7 +69,9 @@ g = from_coo(rng.integers(0, n, nnz), rng.integers(0, n, nnz),
              n_src=n, n_dst=n)
 X = jnp.asarray(rng.normal(size=(n, 64)).astype(np.float32))
 ref = gspmm(g, "u_copy_add_v", u=X, strategy="segment")
-mesh = jax.make_mesh((8,), ("data",))
+# Auto axes: the ring rejects jax.make_mesh's default Explicit ones
+mesh = jax.make_mesh((8,), ("data",),
+                     axis_types=(jax.sharding.AxisType.Auto,))
 with use_ring(mesh):
     out = gspmm(g, "u_copy_add_v", u=X)        # auto
     assert planner.last_plan("u_copy_add_v") == "ring", \
@@ -140,3 +142,61 @@ def test_train_partitioned_exact_and_delayed():
     r = run_multidevice(_TRAIN_PROG)
     assert r.returncode == 0, r.stderr[-3000:]
     assert "TRAIN_OK" in r.stdout
+
+
+def test_ring_rejects_explicit_mesh_axes():
+    """jax.make_mesh builds Explicit axes by default; the ring needs Auto
+    ones and says so at the door instead of failing inside a gather."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from repro.core import build_partition, from_coo, ring_gspmm, use_ring
+    from repro.launch.mesh import make_shard_mesh
+
+    explicit = jax.make_mesh((1,), ("data",))
+    assert explicit.axis_types == (jax.sharding.AxisType.Explicit,)
+    with pytest.raises(ValueError, match="AxisType.Auto"):
+        with use_ring(explicit):
+            pass
+    rng = np.random.default_rng(0)
+    g = from_coo(rng.integers(0, 16, 40), rng.integers(0, 16, 40),
+                 n_src=16, n_dst=16)
+    pg = build_partition(g, 1)
+    x = pg.scatter_nodes(jnp.ones((16, 4), jnp.float32))
+    w = jnp.where(pg.mask, 1.0, 0.0)
+    with pytest.raises(ValueError, match="AxisType.Auto"):
+        ring_gspmm(pg, x, w, mesh=explicit)
+    # the repo's own mesh helper builds Auto axes, which the ring runs on
+    auto = make_shard_mesh(1)
+    with use_ring(auto):
+        out = ring_gspmm(pg, x, w, mesh=auto)
+    np.testing.assert_allclose(np.asarray(pg.gather_nodes(out, 16)),
+                               np.asarray(pg.gather_nodes(
+                                   ring_gspmm(pg, x, w), 16)))
+
+
+def test_partitioned_bench_sweeps_in_process_off_cpu(monkeypatch):
+    """On an accelerator the benchmark's parent already holds the chips:
+    the shard sweep runs in-process over at most the device count, and
+    no emulation child is started."""
+    import subprocess
+
+    import jax
+
+    from benchmarks import fig_partitioned as fp
+
+    seen = []
+    monkeypatch.setattr(fp, "_baseline", lambda *a: {})
+    monkeypatch.setattr(fp, "powerlaw_ring_rows", lambda: None)
+    monkeypatch.setattr(fp, "sweep",
+                        lambda cfg: seen.append(cfg["shards"]) or iter(()))
+    monkeypatch.setattr(subprocess, "run", lambda *a, **k: pytest.fail(
+        "started an emulation child"))
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(jax, "devices", lambda: [object()] * 4)
+    fp.main()
+    assert seen and seen[0] and max(seen[0]) <= 4
+    monkeypatch.setattr(jax, "devices", lambda: [object()])
+    with pytest.raises(RuntimeError, match="needs at least"):
+        fp.main()
