@@ -12,7 +12,6 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core import from_coo, gspmm, planner
-from repro.core.binary_reduce import parse_op
 from repro.data import rmat_graph
 
 from .common import time_fn, row
@@ -54,10 +53,7 @@ def main(d: int = 128, strategy: str = None):
                 continue   # edge-output configs have no blocked-pull stage
             fn = jax.jit(lambda u, v, e, s=s, nm=name:
                          gspmm(g, nm, u=u, v=v, e=e, strategy=s))
-            # auto rows feed drift: measured median lands next to the
-            # plan row's predicted cost (keyed by the canonical spec)
-            op = parse_op(name).name if s == "auto" else None
-            times[s] = time_fn(fn, U, V, E, iters=5, warmup=2, op=op)
+            times[s] = time_fn(fn, U, V, E, iters=5, warmup=2)
         base = times["push"]
         optimized = [k for k in times if k != "push"]
         best_name = (min(optimized, key=lambda k: times[k])

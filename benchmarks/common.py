@@ -4,20 +4,16 @@ process-wide result collector so ``run.py`` can emit BENCH_*.json.
 Every timing loop fences with ``jax.block_until_ready`` — async
 dispatch otherwise returns before the work runs and the row measures
 dispatch latency, not the kernel.  Rows are mirrored into the
-telemetry registry (``bench.<name>`` gauges), and callers may tag a
-measurement with the planner op it exercises (``op=``) so
-``planner.drift_report()`` gets a measured wall time next to the
-predicted cost for that plan row.
+telemetry registry (``bench.<name>`` gauges).
 """
 from __future__ import annotations
 
 import time
-from typing import Callable, Dict, List, Optional
+from typing import Callable, Dict, List
 
 import jax
 import numpy as np
 
-from repro.obs import events as _obs_events
 from repro.obs import metrics as _obs_metrics
 
 # Every row() call records here; benchmarks.run dumps it as JSON along
@@ -26,13 +22,8 @@ RESULTS: List[Dict] = []
 
 
 def time_fn(fn: Callable, *args, iters: int = 5, warmup: int = 2,
-            op: Optional[str] = None, **kw) -> float:
-    """Median seconds per call; jit warmup excluded.
-
-    ``op`` (optional) attributes the median to a planner plan-log key
-    (e.g. ``"u_copy_add_v"`` or ``"attn:fused"``) as a measured event,
-    feeding the predicted-vs-measured drift report.
-    """
+            **kw) -> float:
+    """Median seconds per call; jit warmup excluded."""
     for _ in range(warmup):
         out = fn(*args, **kw)
         jax.block_until_ready(out)
@@ -42,10 +33,7 @@ def time_fn(fn: Callable, *args, iters: int = 5, warmup: int = 2,
         out = fn(*args, **kw)
         jax.block_until_ready(out)
         ts.append(time.perf_counter() - t0)
-    med = float(np.median(ts))
-    if op is not None:
-        _obs_events.measured_event(op, med)
-    return med
+    return float(np.median(ts))
 
 
 def row(name: str, seconds: float, derived: str = "") -> str:

@@ -45,19 +45,15 @@ if QUICK:
     D_IN = 16
 
 
-def _sweep_strategies(tag: str, agg, grad, args, note: str,
-                      op: str = "hetero:u_w_mean_v") -> float:
+def _sweep_strategies(tag: str, agg, grad, args, note: str) -> float:
     """Time loop/fused/auto × fwd/fwd+bwd; print + record the rows.
 
     ``agg(strategy)``/``grad(strategy)`` return jitted callables over
-    ``args``. Returns the forward fused-over-loop speedup. The auto
-    forward row is attributed to the hetero plan-log key (``op``) so
-    the drift report gets a measurement for the planner's choice.
+    ``args``. Returns the forward fused-over-loop speedup.
     """
     t = {}
     for s in ("loop", "fused", "auto"):
-        t[s, "fwd"] = time_fn(agg(s), *args, iters=5,
-                              op=op if s == "auto" else None)
+        t[s, "fwd"] = time_fn(agg(s), *args, iters=5)
         t[s, "bwd"] = time_fn(grad(s), *args, iters=5)
     for phase in ("fwd", "bwd"):
         sp = t["loop", phase] / max(t["fused", phase], 1e-12)

@@ -89,9 +89,7 @@ def bench_attention(tag: str, g, note: str, pallas: bool = False) -> float:
     fns = _attention_fns(g, pallas=pallas)
     t = {}
     for name, (fwd, fwdbwd) in fns.items():
-        t[name, "fwd"] = time_fn(fwd, el, er, z, iters=5,
-                                 op="attn:fused" if name == "fused"
-                                 else None)
+        t[name, "fwd"] = time_fn(fwd, el, er, z, iters=5)
         t[name, "bwd"] = time_fn(fwdbwd, el, er, z, iters=5)
     for phase in ("fwd", "bwd"):
         sp = t["multipass", phase] / max(t["fused", phase], 1e-12)
@@ -118,9 +116,7 @@ def bench_gsddmm_strategies(tag: str, g, note: str) -> None:
     for s in ("canonical", "gather"):
         fn = jax.jit(lambda el, er, _s=s: gsddmm(
             g, "u_add_v_copy_e", u=el, v=er, strategy=_s))
-        t[s] = time_fn(fn, el, er, iters=5,
-                       op="sddmm:u_add_v_copy_e" if s == "canonical"
-                       else None)
+        t[s] = time_fn(fn, el, er, iters=5)
     sp = t["gather"] / max(t["canonical"], 1e-12)
     print(row(f"{tag}_logits_gather", t["gather"], note))
     print(row(f"{tag}_logits_canonical", t["canonical"],

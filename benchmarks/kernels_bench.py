@@ -32,8 +32,7 @@ def main(strategy: str = None):
         for s in strategies:
             fn = jax.jit(lambda x, s=s:
                          copy_reduce(g, x, "sum", strategy=s))
-            t = time_fn(fn, x, iters=5, warmup=2,
-                        op="u_copy_add_v" if s == "auto" else None)
+            t = time_fn(fn, x, iters=5, warmup=2)
             gbps = (g.n_edges * d * 4) / t / 1e9
             tag = f"{gbps:.1f}GB/s-gathered"
             if s == "auto":

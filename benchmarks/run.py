@@ -92,17 +92,16 @@ def main() -> None:
     plans = {f"{op}|{requested}": chosen
              for (op, requested), chosen in planner.plan_log().items()}
     from repro import obs
-    drift = planner.drift_report()
     with open(out_path, "w") as f:
         json.dump({"section": args.only or "all",
                    "strategy": args.strategy,
                    "rows": common.RESULTS,
                    "plans": plans,
                    "metrics": obs.snapshot(),
-                   "plan_events": obs.plan_events(),
-                   "drift": drift}, f, indent=2, sort_keys=True)
-    print(f"# wrote {out_path} ({len(common.RESULTS)} rows, "
-          f"{len(drift)} drift rows)", file=sys.stderr)
+                   "plan_events": obs.plan_events()},
+                  f, indent=2, sort_keys=True)
+    print(f"# wrote {out_path} ({len(common.RESULTS)} rows)",
+          file=sys.stderr)
     if args.trace:
         obs.export_chrome_trace(args.trace)
         print(f"# wrote {args.trace} ({len(obs.trace_events())} span "

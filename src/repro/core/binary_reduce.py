@@ -32,7 +32,6 @@ import jax.numpy as jnp
 from . import planner
 from . import strategies as S
 from .graph import Graph
-from ..obs.events import timed as _timed
 from .tiling import ELLPack, TilePack
 
 __all__ = ["BRSpec", "parse_op", "gspmm", "gsddmm", "copy_reduce",
@@ -210,10 +209,8 @@ def gspmm(g: Graph, op_name: str, *,
     plan = planner.plan_gspmm(g, spec, lhs_data, rhs_data,
                               requested=strategy, cache=cache,
                               ell=ell, tiles=tiles, runner=runner)
-    # eager calls are fenced + timed under the op's plan-log key, so
-    # drift_report can hold the cost model against reality
-    out = _timed(spec.name,
-                 lambda: _execute(g, spec, lhs_data, rhs_data, plan))
+    with jax.named_scope(f"gspmm.{spec.name}"):
+        out = _execute(g, spec, lhs_data, rhs_data, plan)
     # node outputs keep the feature operand's dtype: a bf16 feature
     # against fp32 edge norms silently promotes the message stream to
     # fp32 under JAX's type rules, which would upcast every layer of a
@@ -289,13 +286,10 @@ def gsddmm(g: Graph, op_name: str, *,
     floating = (jnp.issubdtype(lhs_data.dtype, jnp.floating)
                 and (rhs_data is None
                      or jnp.issubdtype(rhs_data.dtype, jnp.floating)))
-    if floating:
-        return _timed(f"sddmm:{spec.name}",
-                      lambda: _sddmm_exec_rev(spec, chosen, g,
-                                              lhs_data, rhs_data))
-    return _timed(f"sddmm:{spec.name}",
-                  lambda: _sddmm_execute(g, spec, lhs_data, rhs_data,
-                                         chosen))
+    with jax.named_scope(f"gsddmm.{spec.name}"):
+        if floating:
+            return _sddmm_exec_rev(spec, chosen, g, lhs_data, rhs_data)
+        return _sddmm_execute(g, spec, lhs_data, rhs_data, chosen)
 
 
 def _sddmm_execute(g: Graph, spec: BRSpec, lhs_data, rhs_data,

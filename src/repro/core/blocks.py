@@ -47,7 +47,6 @@ import jax.numpy as jnp
 import numpy as np
 
 from . import planner
-from ..obs.events import timed as _timed
 from .binary_reduce import (BINARY_OPS, BRSpec, _NEEDS_OTHER, _as2d,
                             _dmsg, _execute, _unbroadcast, gspmm,
                             parse_op)
@@ -329,23 +328,10 @@ def block_gspmm(bg: BlockGraph, op_name: str, *,
                                  gather_available=bg.has_reverse,
                                  runner=bwd_runner,
                                  dtype=str(lhs_data.dtype))
-    # eager calls (serve fan-out, the sampled-train drift probe) are
-    # fenced + timed under the block's plan-log key; in-trace calls
-    # pass straight through
-    if bwd == "gather":
-        return _timed(f"block:{spec.name}",
-                      lambda: _block_exec_rev(spec, chosen, bg,
-                                              lhs_data, rhs_data))
-    if jnp.issubdtype(lhs_data.dtype, jnp.floating):
-        # route the scatter backward through a custom_vjp shim so the
-        # autodiff-derived bwd is also fenced + timed as block_bwd:<op>
-        # when it runs eagerly (same computation either way)
-        return _timed(f"block:{spec.name}",
-                      lambda: _block_exec_scatter(spec, chosen, bg,
-                                                  lhs_data, rhs_data))
-    return _timed(f"block:{spec.name}",
-                  lambda: _block_execute(bg, spec, lhs_data, rhs_data,
-                                         chosen))
+    with jax.named_scope(f"block_gspmm.{spec.name}"):
+        if bwd == "gather":
+            return _block_exec_rev(spec, chosen, bg, lhs_data, rhs_data)
+        return _block_execute(bg, spec, lhs_data, rhs_data, chosen)
 
 
 def _block_execute(bg: BlockGraph, spec: BRSpec, lhs_data, rhs_data,
@@ -361,34 +347,6 @@ def _block_execute(bg: BlockGraph, spec: BRSpec, lhs_data, rhs_data,
                         reason="block")
     out = _execute(bg.g, spec, lhs_data, rhs_data, plan)
     return out[: bg.n_dst_real]
-
-
-@partial(jax.custom_vjp, nondiff_argnums=(0, 1))
-def _block_exec_scatter(spec: BRSpec, chosen: str, bg: BlockGraph,
-                        lhs_data, rhs_data):
-    """Scatter-strategy execute whose backward is the plain autodiff
-    VJP of :func:`_block_execute`, replayed inside ``_timed`` so eager
-    callers (the sampled-train drift probe, serve fan-out) record a
-    ``block_bwd:<op>`` measurement for scatter just like the gather
-    path does."""
-    return _block_execute(bg, spec, lhs_data, rhs_data, chosen)
-
-
-def _block_exec_scatter_fwd(spec, chosen, bg, lhs_data, rhs_data):
-    out, vjp = jax.vjp(
-        lambda l, r: _block_execute(bg, spec, l, r, chosen),
-        lhs_data, rhs_data)
-    # jax.vjp returns a tree_util.Partial — a valid pytree residual
-    return out, vjp
-
-
-def _block_exec_scatter_bwd(spec, chosen, vjp, ct):
-    dlhs, drhs = _timed(f"block_bwd:{spec.name}", lambda: vjp(ct))
-    return None, dlhs, drhs
-
-
-_block_exec_scatter.defvjp(_block_exec_scatter_fwd,
-                           _block_exec_scatter_bwd)
 
 
 # --------------------------------------------------------------------- #
@@ -529,11 +487,8 @@ def _block_exec_rev_fwd(spec, fwd_strategy, bg, lhs_data, rhs_data):
 
 def _block_exec_rev_bwd(spec, fwd_strategy, res, ct):
     bg, lhs_data, rhs_data, arg = res
-    # executes eagerly under an un-jitted vjp replay (the drift probe),
-    # where _timed measures the gather backward as block_bwd:<op>
-    dlhs, drhs = _timed(
-        f"block_bwd:{spec.name}",
-        lambda: _reverse_grads(bg, spec, lhs_data, rhs_data, ct, arg=arg))
+    dlhs, drhs = _reverse_grads(bg, spec, lhs_data, rhs_data, ct,
+                                arg=arg)
     return None, dlhs, drhs
 
 

@@ -23,7 +23,6 @@ import jax.numpy as jnp
 
 from . import planner
 from ..compat import trace_state_clean
-from ..obs.events import timed as _timed
 from .binary_reduce import gspmm
 from .blocks import BlockGraph, block_gspmm
 from .graph import Graph
@@ -42,16 +41,19 @@ def edge_softmax(g: Graph, logits: jnp.ndarray,
     the two node-output reductions route through the planner (pass
     ``cache`` to reuse a per-graph :class:`PlanCache` inside ``jit``).
     """
-    maxv = gspmm(g, "e_copy_max_v", e=logits, strategy=strategy,
-                 cache=cache)
-    # align with edge_softmax_fused: a zero-in-degree node's max is the
-    # reduce identity (-inf) on any strategy that skips the degree
-    # finalize — never let it reach the subtract
-    maxv = jnp.where(jnp.isfinite(maxv), maxv, jnp.zeros((), maxv.dtype))
-    shifted = gspmm(g, "e_sub_v_copy_e", e=logits, v=maxv, strategy=strategy)
-    ex = jnp.exp(shifted)
-    z = gspmm(g, "e_copy_add_v", e=ex, strategy=strategy, cache=cache)
-    return gspmm(g, "e_div_v_copy_e", e=ex, v=z, strategy=strategy)
+    with jax.named_scope("edge_softmax"):
+        maxv = gspmm(g, "e_copy_max_v", e=logits, strategy=strategy,
+                     cache=cache)
+        # align with edge_softmax_fused: a zero-in-degree node's max is
+        # the reduce identity (-inf) on any strategy that skips the
+        # degree finalize — never let it reach the subtract
+        maxv = jnp.where(jnp.isfinite(maxv), maxv,
+                         jnp.zeros((), maxv.dtype))
+        shifted = gspmm(g, "e_sub_v_copy_e", e=logits, v=maxv,
+                        strategy=strategy)
+        ex = jnp.exp(shifted)
+        z = gspmm(g, "e_copy_add_v", e=ex, strategy=strategy, cache=cache)
+        return gspmm(g, "e_div_v_copy_e", e=ex, v=z, strategy=strategy)
 
 
 def block_edge_softmax(bg: BlockGraph, logits: jnp.ndarray,
@@ -67,34 +69,37 @@ def block_edge_softmax(bg: BlockGraph, logits: jnp.ndarray,
     sees exactly their real edges; pad edges' output values are garbage
     but masked out of every downstream block aggregation.
     """
-    x = logits[:, None] if logits.ndim == 1 else logits
-    pad = jnp.zeros((1,) + x.shape[1:], x.dtype)
-    maxv = block_gspmm(bg, "e_copy_max_v", e=x, strategy=strategy,
-                       bwd_strategy=bwd_strategy)
-    shifted = gspmm(bg.g, "e_sub_v_copy_e", e=x,
-                    v=jnp.concatenate([maxv, pad], axis=0))
-    ex = jnp.exp(shifted)
-    z = block_gspmm(bg, "e_copy_add_v", e=ex, strategy=strategy,
-                    bwd_strategy=bwd_strategy)
-    # dummy row gets z=1 so pad edges divide by a finite value; every
-    # real edge's destination has ≥ 1 real edge, so z > 0 on real rows
-    zp = jnp.concatenate([z, jnp.ones_like(pad)], axis=0)
-    out = gspmm(bg.g, "e_div_v_copy_e", e=ex, v=zp)
-    return out[:, 0] if logits.ndim == 1 else out
+    with jax.named_scope("edge_softmax"):
+        x = logits[:, None] if logits.ndim == 1 else logits
+        pad = jnp.zeros((1,) + x.shape[1:], x.dtype)
+        maxv = block_gspmm(bg, "e_copy_max_v", e=x, strategy=strategy,
+                           bwd_strategy=bwd_strategy)
+        shifted = gspmm(bg.g, "e_sub_v_copy_e", e=x,
+                        v=jnp.concatenate([maxv, pad], axis=0))
+        ex = jnp.exp(shifted)
+        z = block_gspmm(bg, "e_copy_add_v", e=ex, strategy=strategy,
+                        bwd_strategy=bwd_strategy)
+        # dummy row gets z=1 so pad edges divide by a finite value;
+        # every real edge's destination has ≥ 1 real edge, so z > 0 on
+        # real rows
+        zp = jnp.concatenate([z, jnp.ones_like(pad)], axis=0)
+        out = gspmm(bg.g, "e_div_v_copy_e", e=ex, v=zp)
+        return out[:, 0] if logits.ndim == 1 else out
 
 
 def edge_softmax_fused(g: Graph, logits: jnp.ndarray) -> jnp.ndarray:
     """Single-pass edge softmax in canonical (dst-sorted) order."""
-    x = logits[:, None] if logits.ndim == 1 else logits
-    m = jnp.take(x, g.eid, axis=0)                       # canonical order
-    kw = dict(num_segments=g.n_dst, indices_are_sorted=True)
-    mx = jax.ops.segment_max(m, g.dst, **kw)
-    mx = jnp.where(jnp.isfinite(mx), mx, jnp.zeros((), m.dtype))
-    ex = jnp.exp(m - jnp.take(mx, g.dst, axis=0))
-    z = jax.ops.segment_sum(ex, g.dst, **kw)
-    out = ex / jnp.take(z, g.dst, axis=0)
-    out = jnp.take(out, g.eid_inv, axis=0)
-    return out[:, 0] if logits.ndim == 1 else out
+    with jax.named_scope("edge_softmax"):
+        x = logits[:, None] if logits.ndim == 1 else logits
+        m = jnp.take(x, g.eid, axis=0)                   # canonical order
+        kw = dict(num_segments=g.n_dst, indices_are_sorted=True)
+        mx = jax.ops.segment_max(m, g.dst, **kw)
+        mx = jnp.where(jnp.isfinite(mx), mx, jnp.zeros((), m.dtype))
+        ex = jnp.exp(m - jnp.take(mx, g.dst, axis=0))
+        z = jax.ops.segment_sum(ex, g.dst, **kw)
+        out = ex / jnp.take(z, g.dst, axis=0)
+        out = jnp.take(out, g.eid_inv, axis=0)
+        return out[:, 0] if logits.ndim == 1 else out
 
 
 # --------------------------------------------------------------------- #
@@ -296,15 +301,11 @@ def fused_attention(g: Graph, el: jnp.ndarray, er: jnp.ndarray,
                 else cache.peek("ell_ragged"))
         if pack is None:
             chosen = "fused"
-    # eager calls are fenced + timed under the attention plan-log key
-    if jnp.issubdtype(z.dtype, jnp.floating):
-        out = _timed("attn:fused",
-                     lambda: _attention_rev(chosen, slope, g, pack,
-                                            el, er, z))
-    else:
-        out = _timed("attn:fused",
-                     lambda: _attention_execute(g, pack, el, er, z,
-                                                slope, chosen))
+    with jax.named_scope("fused_attention"):
+        if jnp.issubdtype(z.dtype, jnp.floating):
+            out = _attention_rev(chosen, slope, g, pack, el, er, z)
+        else:
+            out = _attention_execute(g, pack, el, er, z, slope, chosen)
     return out[:, 0, :] if squeeze else out
 
 

@@ -51,7 +51,6 @@ import numpy as np
 from . import planner
 from ..compat import trace_state_clean
 from . import strategies as S
-from ..obs.events import timed as _timed
 from .binary_reduce import parse_op, _execute
 from .graph import Graph, from_coo
 
@@ -666,15 +665,10 @@ def hetero_gspmm(rg: RelGraph, u: jnp.ndarray, *,
         pack = rg.cache.ell() if eager else rg.cache.peek("ell")
         if pack is None:
             chosen = "fused"    # in-trace without a prebuilt pack
-    # eager calls are fenced + timed under the hetero plan-log key
     if reduce in ("sum", "mean") and chosen in ("fused", "ell"):
-        return _timed(f"hetero:{op_name}",
-                      lambda: _hetero_fused_rev(reduce, chosen, rg, u, w,
-                                                basis, coeff, e))
-    return _timed(f"hetero:{op_name}",
-                  lambda: _exec_hetero(rg, u, w, basis, coeff,
-                                       _scale(rg, e, reduce), reduce,
-                                       chosen))
+        return _hetero_fused_rev(reduce, chosen, rg, u, w, basis, coeff, e)
+    return _exec_hetero(rg, u, w, basis, coeff, _scale(rg, e, reduce),
+                        reduce, chosen)
 
 
 # --------------------------------------------------------------------- #

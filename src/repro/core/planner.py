@@ -47,7 +47,7 @@ from .tiling import (ELLClass, ELLPack, TilePack, build_ell,
                      build_ell_ragged, build_ell_uniform, build_tiles)
 from ..obs import events as _obs_events
 from ..obs import metrics as _obs_metrics
-from ..obs.events import drift_report, plan_events  # noqa: F401 (re-export)
+from ..obs.events import plan_events  # noqa: F401 (re-export)
 from ..optim.compression import wire_bytes as _wire_bytes
 
 __all__ = ["GraphStats", "PlanCache", "Plan", "get_plan_cache",
@@ -63,7 +63,7 @@ __all__ = ["GraphStats", "PlanCache", "Plan", "get_plan_cache",
            "clear_sddmm_plans", "ATTN_STRATEGIES", "plan_attention",
            "SERVE_MODES", "plan_serve", "clear_serve_plans",
            "use_ring", "active_ring", "RingContext",
-           "drift_report", "plan_events"]
+           "plan_events"]
 
 STRATEGIES = ("push", "segment", "ell", "onehot", "pallas", "ring")
 
@@ -862,7 +862,6 @@ def _plan_auto(spec, lhs_data, rhs_data, stats, ok, cache, runner,
         if winner is None or winner not in candidates:
             times = {s: _measure(runner, s) for s in candidates}
             winner = min(times, key=times.get)
-            _obs_events.measured_event(spec.name, times[winner])
             cache._autotuned[key] = winner
         return winner, "autotune"
     ctx = active_ring()
@@ -955,7 +954,6 @@ def plan_block_gspmm(signature: Tuple[int, int, int, int], spec, d: int,
             elif _MODE == "autotune" and runner is not None:
                 times = {s: _measure(runner, s) for s in candidates}
                 chosen = min(times, key=times.get)
-                _obs_events.measured_event(log_name, times[chosen])
             else:
                 stats = block_stats(*signature)
                 chosen = min(candidates,
@@ -1082,7 +1080,6 @@ def plan_block_vjp(signature: Tuple[int, int, int, int], spec, d: int,
                 times = {s: _measure(runner, s)
                          for s in BLOCK_BWD_STRATEGIES}
                 chosen = min(times, key=times.get)
-                _obs_events.measured_event(log_name, times[chosen])
             else:
                 chosen = min(BLOCK_BWD_STRATEGIES,
                              key=lambda s: _block_bwd_cost(
@@ -1193,7 +1190,6 @@ def plan_hetero(signature: Tuple[int, int, int, int], op_name: str,
             if _MODE == "autotune" and runner is not None:
                 times = {s: _measure(runner, s) for s in cand}
                 chosen = min(times, key=times.get)
-                _obs_events.measured_event(log_name, times[chosen])
             else:
                 chosen = min(cand, key=lambda s: _hetero_cost(
                     s, signature, d, backend, stats))
@@ -1330,7 +1326,6 @@ def plan_sddmm(signature: Tuple[int, int, int], spec, d: int,
             if _MODE == "autotune" and runner is not None:
                 times = {s: _measure(runner, s) for s in cand}
                 chosen = min(times, key=times.get)
-                _obs_events.measured_event(log_name, times[chosen])
             else:
                 chosen = min(cand, key=lambda s: _sddmm_cost(
                     s, n_edges, d, backend))

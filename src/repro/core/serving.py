@@ -45,7 +45,6 @@ import numpy as np
 
 from . import planner
 from ..obs import metrics as _obs_metrics
-from ..obs.events import measured_event as _measured_event
 from ..obs.spans import span as _span
 
 __all__ = ["CacheStats", "FeatureCache", "MicroBatch", "MicroBatcher",
@@ -541,11 +540,10 @@ class GNNServer:
         else:
             out = self._serve_fanout(batch)
         self.served_batches += 1
-        # the measured side of the serve:infer plan row + the batch
-        # latency histogram (out is host-side here — nothing in flight)
-        dt = time.perf_counter() - t0
-        _measured_event("serve:infer", dt)
-        _obs_metrics.histogram("serve.batch_seconds").observe(dt)
+        # the batch latency histogram (out is host-side here — nothing
+        # in flight)
+        _obs_metrics.histogram("serve.batch_seconds").observe(
+            time.perf_counter() - t0)
         return out
 
     def serve(self, requests: Sequence[Tuple[int, Sequence[int]]]

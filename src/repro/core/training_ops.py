@@ -82,24 +82,29 @@ def weighted_copy_reduce(tg: TrainingGraph, x: jnp.ndarray,
     """out[v] = Σ_{(u→v)=e} w[e]·x[u] — blocked pull fwd AND bwd.
 
     ``x``: (n_src, d); ``w``: (n_edges, 1) caller edge order (pass ones
-    for plain CR-sum).
+    for plain CR-sum). Its device ops are named under
+    ``weighted_copy_reduce`` (forward) and ``weighted_copy_reduce.bwd``.
     """
-    return _pull_weighted(tg.g, tg.ell, x, w)
+    with jax.named_scope("weighted_copy_reduce"):
+        return _pull_weighted(tg.g, tg.ell, x, w)
 
 
 def _wcr_fwd(tg, x, w):
-    return _pull_weighted(tg.g, tg.ell, x, w), (tg, x, w)
+    with jax.named_scope("weighted_copy_reduce"):
+        return _pull_weighted(tg.g, tg.ell, x, w), (tg, x, w)
 
 
 def _wcr_bwd(res, ct):
     tg, x, w = res
-    # ∂x: pull over the reverse graph (edge ids preserved by reverse())
-    dx = _pull_weighted(tg.g_rev, tg.ell_rev, ct, w).astype(x.dtype)
-    # ∂w: per-edge dot in caller edge order
-    g = tg.g
-    dot = jnp.sum(jnp.take(x, g.src, axis=0)
-                  * jnp.take(ct, g.dst, axis=0), axis=-1, keepdims=True)
-    dw = jnp.take(dot, g.eid_inv, axis=0).astype(w.dtype)
+    with jax.named_scope("weighted_copy_reduce.bwd"):
+        # ∂x: pull over the reverse graph (edge ids kept by reverse())
+        dx = _pull_weighted(tg.g_rev, tg.ell_rev, ct, w).astype(x.dtype)
+        # ∂w: per-edge dot in caller edge order
+        g = tg.g
+        dot = jnp.sum(jnp.take(x, g.src, axis=0)
+                      * jnp.take(ct, g.dst, axis=0), axis=-1,
+                      keepdims=True)
+        dw = jnp.take(dot, g.eid_inv, axis=0).astype(w.dtype)
     return None, dx, dw
 
 

@@ -5,6 +5,12 @@ device — the classic overlap. :class:`Prefetcher` keeps ``depth``
 minibatches in flight on a daemon thread (``depth=2`` is the
 double-buffer: one batch being consumed, one being sampled), so the
 host sampler hides behind device time instead of serializing with it.
+The producer numbers its items from one process-wide sequence and
+builds each under a ``prefetch.produce`` span carrying that number as
+``batch``; the consumer's time blocked on the queue is a
+``prefetch.wait`` span with the same ``batch``, so one minibatch's
+spans share an identifier across the two threads (the sampler's
+``sampler.*`` spans inherit it from ``prefetch.produce``).
 
 :class:`SignatureTracker` watches the static shape signatures of the
 minibatches that reach the jitted step. The sampler pads every batch to
@@ -23,10 +29,14 @@ from typing import Iterable, Iterator, List, Optional, Sequence
 
 import numpy as np
 
+from ..obs.spans import span as _span
+
 __all__ = ["Prefetcher", "prefetch", "SignatureTracker",
            "ServeRequest", "RequestQueue"]
 
 _DONE = object()
+# item numbers, unique in the process (the spans' correlation key)
+_ITEM_IDS = itertools.count()
 
 
 class Prefetcher:
@@ -50,12 +60,17 @@ class Prefetcher:
 
     def _fill(self, it: Iterator) -> None:
         try:
-            for item in it:
+            while True:
+                k = next(_ITEM_IDS)
+                with _span("prefetch.produce", args={"batch": k}):
+                    item = next(it, _DONE)
+                if item is _DONE:
+                    break
                 # bounded put that notices close(): never leaves the
                 # producer blocked (and then hard-killed mid-XLA-call)
                 while not self._stop.is_set():
                     try:
-                        self._q.put(item, timeout=0.1)
+                        self._q.put((k, item), timeout=0.1)
                         break
                     except queue.Full:
                         continue
@@ -92,7 +107,10 @@ class Prefetcher:
     def __next__(self):
         if self._closed:
             raise StopIteration
-        item = self._q.get()
+        with _span("prefetch.wait") as sp:
+            item = self._q.get()
+            if item is not _DONE:
+                sp.set(batch=item[0])
         if item is _DONE:
             # re-queue the sentinel: exhausted iterators must keep
             # raising StopIteration instead of blocking a later next()
@@ -103,7 +121,7 @@ class Prefetcher:
             if self._err is not None:
                 raise self._err
             raise StopIteration
-        return item
+        return item[1]
 
 
 def prefetch(it: Iterable, depth: int = 2) -> Iterator:

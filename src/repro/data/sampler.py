@@ -45,6 +45,8 @@ import numpy as np
 
 from ..core.blocks import BlockGraph
 from ..core.graph import Graph, from_coo
+from ..obs.metrics import counter as _counter
+from ..obs.spans import span as _span
 
 
 @jax.tree_util.register_pytree_node_class
@@ -212,9 +214,25 @@ class NeighborSampler:
         point at the extra dummy row, so real rows are untouched and a
         single jitted step serves every batch. Consumers slice
         ``[:n_dst]`` (``block_gspmm`` does it internally).
+
+        The call is a ``sampler.sample`` span whose args count the real
+        sampled edges and sources against their padded slots over all
+        blocks (``edges``, ``edge_slots``, ``src``, ``src_slots``), with
+        one ``sampler.draw``, ``sampler.graph`` and ``sampler.upload``
+        child per block (and a last ``sampler.upload`` for the seeds'
+        arrays); the registry counters ``sampler.edges`` and
+        ``sampler.edge_slots`` add up the same counts.
         """
         if rng is None:
             rng = self.rng
+        with _span("sampler.sample") as sp:
+            mb, counts = self._sample(seeds, labels, rng)
+            sp.set(**counts)
+        _counter("sampler.edges").inc(counts["edges"])
+        _counter("sampler.edge_slots").inc(counts["edge_slots"])
+        return mb
+
+    def _sample(self, seeds, labels, rng):
         seeds = np.asarray(seeds, np.int64)
         labels = np.asarray(labels, np.int64)
         n_real_seeds = len(seeds)
@@ -230,99 +248,114 @@ class NeighborSampler:
 
         blocks: List[SampledBlock] = []
         frontier = seeds
+        counts = dict(edges=0, edge_slots=0, src=0, src_slots=0)
         for li, fanout in enumerate(reversed(self.fanouts)):
             n_dst = self.layer_sizes[li]
             n_src_pad = self.layer_sizes[li + 1]
             n_edges_pad = n_dst * fanout
-            kmask, eslot, _ = self._sample_layer(self.indptr, rng,
-                                                 frontier, fanout)
-            # real sampled edges in row-major (canonical) order
-            jj, kk = np.nonzero(kmask)
-            nbs = self.src[eslot[jj, kk]]
-            # dst-first source numbering: src slot j == dst node j, so a
-            # layer can read its destinations' own features as h[:n_dst].
-            # First-occurrence slot table (reversed writes: first wins);
-            # a stamp != current generation means "unassigned".
-            self._gen += 1
-            slot, gen = self._slot, self._slot_gen
-            idxs = np.nonzero(frontier >= 0)[0]
-            fv = frontier[idxs][::-1]
-            slot[fv] = idxs[::-1]
-            gen[fv] = self._gen
-            # newly discovered neighbors, in first-occurrence order
-            new_vals = nbs[gen[nbs] != self._gen]
-            uvals, first = np.unique(new_vals, return_index=True)
-            new_unique = uvals[np.argsort(first, kind="stable")]
-            slot[new_unique] = n_dst + np.arange(len(new_unique))
-            gen[new_unique] = self._gen
-            n_real_src = n_dst + len(new_unique)
-            # pad sources to static size; dummy source = last slot
-            src_ids = np.concatenate([
-                frontier, new_unique,
-                np.full(n_src_pad - n_real_src, -1, np.int64)])
-            srcs = slot[nbs]
-            n_real = len(jj)
-            nbr = np.full((n_dst, fanout), n_src_pad - 1, np.int32)
-            nbr[jj, kk] = srcs
-            nbr_eid = np.zeros((n_dst, fanout), np.int32)
-            nbr_eid[jj, kk] = np.arange(n_real, dtype=np.int32)
-            nbr_mask = kmask
-            norms = (1.0 / np.sqrt(self.deg_out[nbs]
-                                   * self.deg_in[frontier[jj]]))
-            # pad edges into the dummy destination row n_dst (never any
-            # real source slot: a pad edge exists only when some row is
-            # under fanout, which leaves the dummy source slot free)
-            pad = n_edges_pad - n_real
-            srcs = np.concatenate([srcs,
-                                   np.full(pad, n_src_pad - 1, np.int64)])
-            dsts = np.concatenate([jj, np.full(pad, n_dst, np.int64)])
-            norms = np.concatenate([norms,
-                                    np.zeros(pad)]).astype(np.float32)
-            # pad slots of the neighbor table index SOME valid edge id;
-            # they are masked, so the value never reaches a reduction
-            nbr_eid[~nbr_mask] = min(n_real, n_edges_pad - 1)
-            real_deg = nbr_mask.sum(axis=1).astype(np.int32)
-            # reverse table: the same edge list stably sorted by source
-            # slot — what the gather backward pulls over. Pad edges
-            # (dummy source = last slot) sort last; their dst is the
-            # dummy row, so a zero cotangent row masks them exactly.
-            rev_eid = np.argsort(srcs, kind="stable").astype(np.int32)
-            rev_src = srcs[rev_eid].astype(np.int32)
-            rev_dst = dsts[rev_eid].astype(np.int32)
-            g = from_coo(srcs, dsts, n_src=n_src_pad, n_dst=n_dst + 1)
-            bg = BlockGraph(g=g, nbr=jnp.asarray(nbr),
-                            nbr_eid=jnp.asarray(nbr_eid),
-                            nbr_mask=jnp.asarray(nbr_mask),
-                            real_deg=jnp.asarray(real_deg),
-                            n_dst_real=n_dst, fanout=fanout,
-                            rev_src=jnp.asarray(rev_src),
-                            rev_dst=jnp.asarray(rev_dst),
-                            rev_eid=jnp.asarray(rev_eid))
-            rel_blk = rel_norm = None
-            if self.rel is not None:
-                # relation id per sampled edge + the per-(dst, relation)
-                # sampled-mean weight 1/|sampled N_r(v)|; pad edges get
-                # rel 0 / weight 0 (they point at the dummy row anyway)
-                rel_e = self.rel[eslot[jj, kk]]
-                key = jj * self.n_rel + rel_e
-                cnt = np.bincount(key,
-                                  minlength=n_dst * max(self.n_rel, 1))
-                rel_blk = jnp.asarray(np.concatenate(
-                    [rel_e, np.zeros(pad, np.int64)]), jnp.int32)
-                rel_norm = jnp.asarray(np.concatenate(
-                    [1.0 / cnt[key],
-                     np.zeros(pad)]).astype(np.float32))
-            blocks.append(SampledBlock(
-                bg=bg, src_ids=jnp.asarray(src_ids, jnp.int32),
-                gcn_norm=jnp.asarray(norms), rel=rel_blk,
-                rel_norm=rel_norm))
+            with _span("sampler.draw"):
+                kmask, eslot, _ = self._sample_layer(self.indptr, rng,
+                                                     frontier, fanout)
+                # real sampled edges in row-major (canonical) order
+                jj, kk = np.nonzero(kmask)
+                nbs = self.src[eslot[jj, kk]]
+                # dst-first source numbering: src slot j == dst node j,
+                # so a layer can read its destinations' own features as
+                # h[:n_dst]. First-occurrence slot table (reversed
+                # writes: first wins); a stamp != current generation
+                # means "unassigned".
+                self._gen += 1
+                slot, gen = self._slot, self._slot_gen
+                idxs = np.nonzero(frontier >= 0)[0]
+                fv = frontier[idxs][::-1]
+                slot[fv] = idxs[::-1]
+                gen[fv] = self._gen
+                # newly discovered neighbors, in first-occurrence order
+                new_vals = nbs[gen[nbs] != self._gen]
+                uvals, first = np.unique(new_vals, return_index=True)
+                new_unique = uvals[np.argsort(first, kind="stable")]
+                slot[new_unique] = n_dst + np.arange(len(new_unique))
+                gen[new_unique] = self._gen
+                n_real_src = n_dst + len(new_unique)
+                # pad sources to static size; dummy source = last slot
+                src_ids = np.concatenate([
+                    frontier, new_unique,
+                    np.full(n_src_pad - n_real_src, -1, np.int64)])
+                srcs = slot[nbs]
+            with _span("sampler.graph"):
+                n_real = len(jj)
+                nbr = np.full((n_dst, fanout), n_src_pad - 1, np.int32)
+                nbr[jj, kk] = srcs
+                nbr_eid = np.zeros((n_dst, fanout), np.int32)
+                nbr_eid[jj, kk] = np.arange(n_real, dtype=np.int32)
+                nbr_mask = kmask
+                norms = (1.0 / np.sqrt(self.deg_out[nbs]
+                                       * self.deg_in[frontier[jj]]))
+                # pad edges into the dummy destination row n_dst (never
+                # any real source slot: a pad edge exists only when some
+                # row is under fanout, which leaves the dummy source slot
+                # free)
+                pad = n_edges_pad - n_real
+                srcs = np.concatenate(
+                    [srcs, np.full(pad, n_src_pad - 1, np.int64)])
+                dsts = np.concatenate([jj, np.full(pad, n_dst, np.int64)])
+                norms = np.concatenate([norms,
+                                        np.zeros(pad)]).astype(np.float32)
+                # pad slots of the neighbor table index SOME valid edge
+                # id; they are masked, so the value never reaches a
+                # reduction
+                nbr_eid[~nbr_mask] = min(n_real, n_edges_pad - 1)
+                real_deg = nbr_mask.sum(axis=1).astype(np.int32)
+                # reverse table: the same edge list stably sorted by
+                # source slot — what the gather backward pulls over. Pad
+                # edges (dummy source = last slot) sort last; their dst
+                # is the dummy row, so a zero cotangent row masks them
+                # exactly.
+                rev_eid = np.argsort(srcs, kind="stable").astype(np.int32)
+                rev_src = srcs[rev_eid].astype(np.int32)
+                rev_dst = dsts[rev_eid].astype(np.int32)
+                g = from_coo(srcs, dsts, n_src=n_src_pad, n_dst=n_dst + 1)
+                rel_e = rel_w = None
+                if self.rel is not None:
+                    # relation id per sampled edge + the per-(dst,
+                    # relation) sampled-mean weight 1/|sampled N_r(v)|;
+                    # pad edges get rel 0 / weight 0 (they point at the
+                    # dummy row anyway)
+                    rel_e = self.rel[eslot[jj, kk]]
+                    key = jj * self.n_rel + rel_e
+                    cnt = np.bincount(key,
+                                      minlength=n_dst * max(self.n_rel, 1))
+                    rel_e = np.concatenate([rel_e, np.zeros(pad, np.int64)])
+                    rel_w = np.concatenate(
+                        [1.0 / cnt[key], np.zeros(pad)]).astype(np.float32)
+            with _span("sampler.upload"):
+                bg = BlockGraph(g=g, nbr=jnp.asarray(nbr),
+                                nbr_eid=jnp.asarray(nbr_eid),
+                                nbr_mask=jnp.asarray(nbr_mask),
+                                real_deg=jnp.asarray(real_deg),
+                                n_dst_real=n_dst, fanout=fanout,
+                                rev_src=jnp.asarray(rev_src),
+                                rev_dst=jnp.asarray(rev_dst),
+                                rev_eid=jnp.asarray(rev_eid))
+                blocks.append(SampledBlock(
+                    bg=bg, src_ids=jnp.asarray(src_ids, jnp.int32),
+                    gcn_norm=jnp.asarray(norms),
+                    rel=(None if rel_e is None
+                         else jnp.asarray(rel_e, jnp.int32)),
+                    rel_norm=None if rel_w is None else jnp.asarray(rel_w)))
+            counts["edges"] += n_real
+            counts["edge_slots"] += n_edges_pad
+            counts["src"] += len(idxs) + len(new_unique)
+            counts["src_slots"] += n_src_pad
             frontier = src_ids
         blocks.reverse()
-        return MiniBatch(blocks=tuple(blocks),
-                         input_ids=blocks[0].src_ids,
-                         seed_ids=jnp.asarray(seeds, jnp.int32),
-                         labels=jnp.asarray(labels, jnp.int32),
-                         label_mask=jnp.asarray(label_mask))
+        with _span("sampler.upload"):
+            mb = MiniBatch(blocks=tuple(blocks),
+                           input_ids=blocks[0].src_ids,
+                           seed_ids=jnp.asarray(seeds, jnp.int32),
+                           labels=jnp.asarray(labels, jnp.int32),
+                           label_mask=jnp.asarray(label_mask))
+        return mb, counts
 
     def batches(self, node_ids: np.ndarray, labels: np.ndarray,
                 drop_last: bool = True) -> Iterator[MiniBatch]:

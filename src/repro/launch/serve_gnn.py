@@ -21,13 +21,11 @@ from typing import Callable, Dict, List, Optional
 import jax
 import numpy as np
 
-from ..core import planner
 from ..core.serving import SERVE_APPS, GNNServer
 from ..data import RequestQueue, make_node_dataset, relational_graph
 from ..models.gnn import gat, gcn, rgcn, sage
 from .compile_cache import setup_compile_cache
-from ..obs import (export_chrome_trace, percentile_nearest_rank, snapshot,
-                   span_coverage)
+from ..obs import export_chrome_trace, percentile_nearest_rank, span_coverage
 
 
 def build_server(app: str, dataset: str, *, mode: str = "auto",
@@ -143,9 +141,6 @@ def main():
     ap.add_argument("--trace", default=None, metavar="OUT.json",
                     help="export the session as Chrome-trace JSON "
                          "(open in Perfetto / chrome://tracing)")
-    ap.add_argument("--drift", action="store_true",
-                    help="print the planner predicted-vs-measured "
-                         "drift report after the session")
     args = ap.parse_args()
     setup_compile_cache()
 
@@ -182,20 +177,6 @@ def main():
         export_chrome_trace(args.trace)
         print(f"[serve_gnn] trace: {len(trace_events())} events → "
               f"{args.trace} (span coverage {span_coverage():.1%})")
-    if args.drift:
-        rows = planner.drift_report()
-        print(f"[serve_gnn] drift report ({len(rows)} rows):")
-        for r in rows:
-            print(f"  {r['op']:28s} {r['chosen']:10s} "
-                  f"pred={r['predicted_cost']:.3g} "
-                  f"meas={1e3 * r['measured_mean_s']:.3f}ms "
-                  f"ratio={r['ratio']:.2f}"
-                  f"{'  DRIFTED' if r['drifted'] else ''}")
-        snap = snapshot()
-        batch_h = snap.get("serve.batch_seconds")
-        if batch_h:
-            print(f"[serve_gnn] serve.batch_seconds: "
-                  f"n={batch_h['count']} mean={1e3 * batch_h['mean']:.3f}ms")
     if res["recompiles_steady"]:
         raise SystemExit("steady-state recompiles detected")
 

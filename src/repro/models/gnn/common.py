@@ -201,7 +201,8 @@ def run_blocks(block_layer: Callable, layers: Sequence, blocks: Sequence,
                h: jnp.ndarray, *, strategy: str = "auto",
                bwd_strategy: str = "auto",
                activation: Callable = jax.nn.relu, train: bool = False,
-               rng=None, drop: float = 0.0) -> jnp.ndarray:
+               rng=None, drop: float = 0.0,
+               scope: str = "block") -> jnp.ndarray:
     """Drive a per-app layer function over a minibatch's blocks.
 
     ``block_layer(lyr, blk, h, strategy=..., bwd_strategy=...)`` maps
@@ -216,16 +217,20 @@ def run_blocks(block_layer: Callable, layers: Sequence, blocks: Sequence,
     scatter / auto — see DESIGN.md §7), threaded to every
     ``block_gspmm`` so the planner's ``block_bwd:<op>`` decisions apply
     inside a differentiated train step.
+
+    Layer ``i`` runs under ``jax.named_scope(f"{scope}.layer{i}")``, so
+    its device ops carry the model's layer in their names.
     """
     if len(layers) != len(blocks):
         raise ValueError(f"{len(layers)} layers but {len(blocks)} blocks: "
                          f"sampler fanouts must match model depth")
     for i, (lyr, blk) in enumerate(zip(layers, blocks)):
-        if train and rng is not None and drop > 0.0:
-            rng, sub = jax.random.split(rng)
-            h = dropout(sub, h, drop, train)
-        h = block_layer(lyr, blk, h, strategy=strategy,
-                        bwd_strategy=bwd_strategy)
-        if i < len(layers) - 1:
-            h = activation(h)
+        with jax.named_scope(f"{scope}.layer{i}"):
+            if train and rng is not None and drop > 0.0:
+                rng, sub = jax.random.split(rng)
+                h = dropout(sub, h, drop, train)
+            h = block_layer(lyr, blk, h, strategy=strategy,
+                            bwd_strategy=bwd_strategy)
+            if i < len(layers) - 1:
+                h = activation(h)
     return h

@@ -99,13 +99,14 @@ def forward(params: Dict, bundle: GraphBundle, x: jnp.ndarray, *,
     for i, lyr in enumerate(params["layers"]):
         hd = lyr["attn_l"].shape[0]     # heads encoded in param shapes
         out = lyr["attn_l"].shape[-1]
-        if train and rng is not None:
-            rng, sub = jax.random.split(rng)
-            h = dropout(sub, h, drop, train)
-        h = _gat_layer(lyr, bundle, h, hd, out, strategy=strategy,
-                       attn=attn)
-        if i < n_layers - 1:
-            h = jax.nn.elu(h)
+        with jax.named_scope(f"gat.layer{i}"):
+            if train and rng is not None:
+                rng, sub = jax.random.split(rng)
+                h = dropout(sub, h, drop, train)
+            h = _gat_layer(lyr, bundle, h, hd, out, strategy=strategy,
+                           attn=attn)
+            if i < n_layers - 1:
+                h = jax.nn.elu(h)
     return h
 
 
@@ -150,7 +151,7 @@ def forward_blocks(params: Dict, blocks, x: jnp.ndarray, *,
     return run_blocks(layer, params["layers"], blocks, x,
                       strategy=strategy, bwd_strategy=bwd_strategy,
                       activation=jax.nn.elu,
-                      train=train, rng=rng, drop=drop)
+                      train=train, rng=rng, drop=drop, scope="gat")
 
 
 def forward_partitioned(params: Dict, pb: PartitionedBundle,

@@ -32,19 +32,21 @@ def forward(params: Dict, bundle: GraphBundle, x: jnp.ndarray, *,
     h = x
     n_layers = len(params["layers"])
     for i, lyr in enumerate(params["layers"]):
-        if train and rng is not None:
-            rng, sub = jax.random.split(rng)
-            h = dropout(sub, h, drop, train)
-        if bundle.use_training_graph(strategy, h.shape[-1]):
-            # mean = weighted CR with 1/deg(dst); blocked pull both ways
-            hn = weighted_copy_reduce(bundle.tg, h,
-                                      bundle.mean_norm[:, None])
-        else:
-            hn = gspmm(bundle.g, "u_copy_mean_v", u=h, strategy=strategy,
-                       cache=bundle.cache)
-        h = linear_apply(lyr, jnp.concatenate([h, hn], axis=-1))
-        if i < n_layers - 1:
-            h = jax.nn.relu(h)
+        with jax.named_scope(f"sage.layer{i}"):
+            if train and rng is not None:
+                rng, sub = jax.random.split(rng)
+                h = dropout(sub, h, drop, train)
+            if bundle.use_training_graph(strategy, h.shape[-1]):
+                # mean = weighted CR with 1/deg(dst); blocked pull both
+                # ways
+                hn = weighted_copy_reduce(bundle.tg, h,
+                                          bundle.mean_norm[:, None])
+            else:
+                hn = gspmm(bundle.g, "u_copy_mean_v", u=h,
+                           strategy=strategy, cache=bundle.cache)
+            h = linear_apply(lyr, jnp.concatenate([h, hn], axis=-1))
+            if i < n_layers - 1:
+                h = jax.nn.relu(h)
     return h
 
 
@@ -68,7 +70,7 @@ def forward_blocks(params: Dict, blocks, x: jnp.ndarray, *,
     return run_blocks(block_layer, params["layers"], blocks, x,
                       strategy=strategy, bwd_strategy=bwd_strategy,
                       activation=jax.nn.relu,
-                      train=train, rng=rng, drop=drop)
+                      train=train, rng=rng, drop=drop, scope="sage")
 
 
 def init_halo(params: Dict, pg):

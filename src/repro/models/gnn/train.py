@@ -29,7 +29,6 @@ import numpy as np
 
 from ...data.pipeline import prefetch
 from ...data.sampler import NeighborSampler
-from ...obs import events as _obs_events
 from ...obs.signatures import SignatureTracker
 from ...obs.spans import span as _span
 from ...optim import (adamw, apply_updates, cast_logits, cast_tree,
@@ -68,9 +67,10 @@ def make_train_step(forward_fn: Callable, strategy: str, lr: float = 1e-2,
             return cross_entropy_loss(cast_logits(logits), labels, mask)
 
         loss, grads = jax.value_and_grad(loss_fn)(params)
-        grads, _ = clip_by_global_norm(grads, clip)
-        ups, opt_state = opt_update(grads, opt_state, params, step_i)
-        params = apply_updates(params, ups)
+        with jax.named_scope("optimizer"):
+            grads, _ = clip_by_global_norm(grads, clip)
+            ups, opt_state = opt_update(grads, opt_state, params, step_i)
+            params = apply_updates(params, ups)
         return params, opt_state, loss
 
     return opt_init, step
@@ -161,9 +161,10 @@ def make_partitioned_train_step(forward_part_fn: Callable,
 
         (loss, (halo_out, comm_out)), grads = jax.value_and_grad(
             loss_fn, has_aux=True)(params)
-        grads, _ = clip_by_global_norm(grads, clip)
-        ups, opt_state = opt_update(grads, opt_state, params, step_i)
-        params = apply_updates(params, ups)
+        with jax.named_scope("optimizer"):
+            grads, _ = clip_by_global_norm(grads, clip)
+            ups, opt_state = opt_update(grads, opt_state, params, step_i)
+            params = apply_updates(params, ups)
         return params, opt_state, loss, halo_out, comm_out
 
     return opt_init, step
@@ -312,39 +313,13 @@ def make_sampled_train_step(forward_blocks_fn: Callable, strategy: str,
                                       mb.label_mask)
 
         loss, grads = jax.value_and_grad(loss_fn)(params)
-        grads, _ = clip_by_global_norm(grads, clip)
-        ups, opt_state = opt_update(grads, opt_state, params, step_i)
-        params = apply_updates(params, ups)
+        with jax.named_scope("optimizer"):
+            grads, _ = clip_by_global_norm(grads, clip)
+            ups, opt_state = opt_update(grads, opt_state, params, step_i)
+            params = apply_updates(params, ups)
         return params, opt_state, loss
 
     return opt_init, step
-
-
-def _drift_probe(forward_blocks_fn, params, mb, feats_pad, strategy,
-                 bwd_strategy) -> None:
-    """Once-per-new-signature eager probe feeding the drift report.
-
-    The jitted train step never executes block ops eagerly, so the
-    ``block:*`` / ``block_bwd:*`` plan rows would have predictions but
-    no measurements. This runs the block forward un-jitted (the timed
-    hooks in core/blocks.py fire) and replays its VJP (the custom
-    gather backward executes eagerly at ``vjp_fn`` call time). It rides
-    the compile batch — a NEW signature already pays a trace+compile —
-    so steady-state per-step time is untouched.
-    """
-    if not _obs_events.enabled():
-        return
-    with _span("train.drift_probe"):
-        x = block_features(feats_pad, mb.input_ids)
-
-        def f(p):
-            return forward_blocks_fn(p, mb.blocks, x, strategy=strategy,
-                                     bwd_strategy=bwd_strategy,
-                                     train=False)
-
-        jax.block_until_ready(f(params))        # eager fwd → block:*
-        out, vjp_fn = jax.vjp(f, params)
-        jax.block_until_ready(vjp_fn(jnp.ones_like(out)))  # block_bwd:*
 
 
 def train_sampled(forward_blocks_fn: Callable, params: Dict, g, feats,
@@ -381,7 +356,7 @@ def train_sampled(forward_blocks_fn: Callable, params: Dict, g, feats,
                "step_time": [], "n_batches": []}
     step_i = 0
     for _ in range(epochs):
-        # one top-level span per epoch; sample/step/probe spans nest
+        # one top-level span per epoch; sample/step spans nest
         # under it, so the exported trace tiles the whole run
         with _span("train.epoch"):
             it = prefetch(sampler.batches(train_ids, labels[train_ids],
@@ -398,15 +373,9 @@ def train_sampled(forward_blocks_fn: Callable, params: Dict, g, feats,
                     if mb is None:
                         break
                     t_sample += time.perf_counter() - t0
-                    # signature-change work is hoisted behind the
-                    # tracker: only a NEW signature (⇒ a fresh compile)
-                    # re-checks the bound — unchanged batches skip the
-                    # per-step accounting (the sampler likewise reuses
-                    # one cached label-mask array per real-seed count
-                    # instead of re-padding)
-                    if tracker.observe_checked(mb.shape_signature()):
-                        _drift_probe(forward_blocks_fn, params, mb,
-                                     feats_pad, strategy, bwd_strategy)
+                    # only a NEW signature (⇒ a fresh compile)
+                    # re-checks the bound
+                    tracker.observe_checked(mb.shape_signature())
                     rng, sub = jax.random.split(rng)
                     t0 = time.perf_counter()
                     with _span("train.step") as sp:

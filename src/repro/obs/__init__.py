@@ -10,13 +10,15 @@ everything; :func:`set_enabled` toggles at runtime):
   :func:`snapshot` describes a whole run.
 * :mod:`.spans` — ``with span("compute") as sp: ...; sp.fence(out)``
   wall-time tracing with ``block_until_ready`` fencing at span exit
-  (device work is attributed to the span that launched it), exportable
+  (device work is attributed to the span that launched it). Each span
+  is also a ``jax.profiler.TraceAnnotation``, so it lands in a profiler
+  trace beside the device ops, and keeps its raw ``perf_counter_ns``
+  bounds, its id and its parent's; JAX's compile phases become spans
+  through one monitoring listener, installed here. The buffer exports
   as Chrome-trace JSON (:func:`export_chrome_trace`, loadable in
   Perfetto / chrome://tracing).
 * :mod:`.events` — the structured plan-event stream: every planner
-  decision row records the cost model's *predicted* cost, eager op
-  executions record *measured* wall time, and :func:`drift_report`
-  surfaces ops where prediction and reality diverge.
+  decision row records the cost model's *predicted* cost.
 
 ``repro.obs`` sits below every other repro package (it imports only
 jax/numpy/stdlib), so core/data/models/launch can all report here
@@ -27,19 +29,20 @@ from .metrics import (Counter, Gauge, Histogram, MetricsRegistry,
                       reset_metrics, enabled, set_enabled,
                       percentile_nearest_rank)
 from .spans import (Span, span, export_chrome_trace, trace_events,
-                    clear_trace, span_coverage)
-from .events import (PLAN_EVENT_FIELDS, DRIFT_FIELDS, plan_event,
-                     measured_event, timed, plan_events, drift_report,
+                    clear_trace, span_coverage, install_compile_listener)
+from .events import (PLAN_EVENT_FIELDS, plan_event, plan_events,
                      clear_events, family_of)
 from .signatures import SignatureTracker
+
+install_compile_listener()
 
 __all__ = [
     "Counter", "Gauge", "Histogram", "MetricsRegistry", "REGISTRY",
     "counter", "gauge", "histogram", "snapshot", "reset_metrics",
     "enabled", "set_enabled", "percentile_nearest_rank",
     "Span", "span", "export_chrome_trace", "trace_events",
-    "clear_trace", "span_coverage",
-    "PLAN_EVENT_FIELDS", "DRIFT_FIELDS", "plan_event", "measured_event",
-    "timed", "plan_events", "drift_report", "clear_events", "family_of",
+    "clear_trace", "span_coverage", "install_compile_listener",
+    "PLAN_EVENT_FIELDS", "plan_event", "plan_events", "clear_events",
+    "family_of",
     "SignatureTracker",
 ]

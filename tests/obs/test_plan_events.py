@@ -1,4 +1,4 @@
-"""Plan-event record format (golden schema) + drift report + e2e.
+"""Plan-event record format (golden schema) + e2e.
 
 The schema tests PIN the record layout — BENCH_*.json consumers and
 the CI artifacts read these dicts, so a field rename/removal is a
@@ -10,8 +10,6 @@ import pytest
 
 from repro import obs
 from repro.core import graph as G
-from repro.core import planner
-from repro.obs import events as E
 
 
 @pytest.fixture(autouse=True)
@@ -27,37 +25,19 @@ def _clean_events():
 def test_plan_event_fields_golden():
     assert obs.PLAN_EVENT_FIELDS == (
         "op", "family", "requested", "chosen", "count",
-        "predicted_cost", "measured_calls", "measured_total_s",
-        "measured_mean_s", "dtype")
-
-
-def test_drift_fields_golden():
-    assert obs.DRIFT_FIELDS == (
-        "op", "family", "requested", "chosen", "predicted_cost",
-        "measured_calls", "measured_mean_s", "family_scale", "ratio",
-        "drifted", "dtype")
+        "predicted_cost", "dtype")
 
 
 def test_plan_event_rows_have_exact_keys():
     obs.plan_event("block:u_copy_add_v", "auto", "segment",
                    predicted_cost=10.0)
-    obs.measured_event("block:u_copy_add_v", 0.01)
     rows = obs.plan_events()
     assert len(rows) == 1
     assert tuple(rows[0].keys()) == obs.PLAN_EVENT_FIELDS
     r = rows[0]
     assert r["family"] == "block"
     assert r["count"] == 1
-    assert r["measured_calls"] == 1
-    assert r["measured_mean_s"] == pytest.approx(0.01)
-
-
-def test_drift_rows_have_exact_keys():
-    obs.plan_event("serve:infer", "auto", "layerwise", predicted_cost=5.0)
-    obs.measured_event("serve:infer", 0.002)
-    rows = planner.drift_report()
-    assert len(rows) == 1
-    assert tuple(rows[0].keys()) == obs.DRIFT_FIELDS
+    assert r["predicted_cost"] == pytest.approx(10.0)
 
 
 def test_plan_event_keyed_by_dtype():
@@ -72,22 +52,6 @@ def test_plan_event_keyed_by_dtype():
     assert all(r["count"] == 1 for r in rows)
 
 
-def test_drift_scale_fit_per_family_dtype():
-    # one family, two dtypes, 100x apart in time-per-cost: a shared
-    # family scale would flag every row as drifted; per-(family, dtype)
-    # scales fit each group on its own and flag none
-    for i, cost in enumerate((10.0, 20.0, 40.0)):
-        obs.plan_event(f"fam:f32op{i}", "auto", "a", predicted_cost=cost,
-                       dtype="float32")
-        obs.measured_event(f"fam:f32op{i}", cost * 1e-3)
-        obs.plan_event(f"fam:b16op{i}", "auto", "a", predicted_cost=cost,
-                       dtype="bfloat16")
-        obs.measured_event(f"fam:b16op{i}", cost * 1e-1)
-    rows = planner.drift_report(threshold=4.0)
-    assert len(rows) == 6
-    assert not any(r["drifted"] for r in rows)
-
-
 def test_family_of():
     assert obs.family_of("u_copy_add_v") == "gspmm"
     assert obs.family_of("block:u_copy_add_v") == "block"
@@ -98,69 +62,17 @@ def test_family_of():
     assert obs.family_of("serve:infer") == "serve"
 
 
-# ------------------------------------------------------------------ #
-# drift semantics
-# ------------------------------------------------------------------ #
-def test_single_row_family_never_drifts():
-    obs.plan_event("gone:x", "auto", "a", predicted_cost=100.0)
-    obs.measured_event("gone:x", 1.0)
-    (r,) = planner.drift_report()
-    # the family scale is fit on this one row → ratio is exactly 1
-    assert r["ratio"] == pytest.approx(1.0)
-    assert not r["drifted"]
-
-
-def test_outlier_within_family_drifts():
-    # three ops whose measured/predicted agree, one 100x off
-    for i, cost in enumerate((10.0, 20.0, 40.0)):
-        op = f"fam:op{i}"
-        obs.plan_event(op, "auto", "a", predicted_cost=cost)
-        obs.measured_event(op, cost * 1e-3)
-    obs.plan_event("fam:bad", "auto", "a", predicted_cost=10.0)
-    obs.measured_event("fam:bad", 10.0 * 1e-3 * 100)
-    rows = planner.drift_report(threshold=4.0)
-    drifted = {r["op"] for r in rows if r["drifted"]}
-    assert drifted == {"fam:bad"}
-    # report is sorted worst-first
-    assert rows[0]["op"] == "fam:bad"
-
-
-def test_unmeasured_and_unpredicted_rows_excluded():
-    obs.plan_event("fam:nopred", "auto", "a")            # no predicted
-    obs.measured_event("fam:nopred", 0.01)
-    obs.plan_event("fam:nomeas", "auto", "a", predicted_cost=3.0)
-    assert planner.drift_report() == []
-
-
-def test_drift_threshold_validated():
-    with pytest.raises(ValueError):
-        planner.drift_report(threshold=1.0)
-
-
 def test_plan_event_disabled_noop():
     prev = obs.set_enabled(False)
     try:
         obs.plan_event("dead:x", "auto", "a", predicted_cost=1.0)
-        obs.measured_event("dead:x", 1.0)
-        E.timed("dead:x", lambda: 7)
     finally:
         obs.set_enabled(prev)
     assert obs.plan_events() == []
 
 
-def test_timed_passes_value_through():
-    assert E.timed("t:passthrough", lambda: 41 + 1) == 42
-    rows = {r["op"]: r for r in obs.plan_events()}
-    # measured-only ops don't appear in plan_events (no plan row) …
-    assert "t:passthrough" not in rows
-    # … but pair it with a plan row and the timing joins up
-    obs.plan_event("t:passthrough", "auto", "x", predicted_cost=1.0)
-    (r,) = obs.plan_events()
-    assert r["measured_calls"] == 1
-
-
 # ------------------------------------------------------------------ #
-# e2e: the real planner paths emit predicted + measured rows
+# e2e: the real planner paths emit predicted rows
 # ------------------------------------------------------------------ #
 def test_gspmm_emits_predicted_and_measured():
     rng = np.random.default_rng(0)
@@ -169,29 +81,10 @@ def test_gspmm_emits_predicted_and_measured():
                    n_src=n, n_dst=n)
     x = jax.numpy.asarray(rng.standard_normal((n, 8)).astype(np.float32))
     from repro.core import gspmm
-    out = gspmm(g, "u_copy_add_v", u=x)          # eager → timed
+    out = gspmm(g, "u_copy_add_v", u=x)
     jax.block_until_ready(out)
     rows = [r for r in obs.plan_events() if r["op"] == "u_copy_add_v"]
     assert rows, "gspmm plan row missing"
+    assert all(tuple(r) == obs.PLAN_EVENT_FIELDS for r in rows)
     assert any(r["predicted_cost"] is not None for r in rows)
-    assert any(r["measured_calls"] > 0 for r in rows)
-    drift_ops = {r["op"] for r in planner.drift_report()}
-    assert "u_copy_add_v" in drift_ops
-
-
-def test_sampled_train_emits_block_and_bwd_measurements():
-    from repro.models.gnn import sage
-    from repro.models.gnn.train import train_sampled
-    rng = np.random.default_rng(0)
-    n, m = 80, 300
-    g = G.from_coo(rng.integers(0, n, m), rng.integers(0, n, m),
-                   n_src=n, n_dst=n)
-    feats = rng.standard_normal((n, 8)).astype(np.float32)
-    labels = rng.integers(0, 3, n)
-    params = sage.init(jax.random.PRNGKey(0), 8, 8, 3)
-    train_sampled(sage.forward_blocks, params, g, feats, labels,
-                  np.arange(60), fanouts=(2, 2), batch_size=32,
-                  epochs=1, max_batches=2)
-    fams = {r["family"] for r in planner.drift_report()}
-    assert "block" in fams
-    assert "block_bwd" in fams
+    assert all(r["requested"] == "auto" and r["count"] >= 1 for r in rows)

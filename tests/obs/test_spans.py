@@ -48,7 +48,8 @@ def test_fence_blocks_on_device_value():
     with obs.span("unit.fenced") as sp:
         y = sp.fence(jnp.arange(512.0) * 2.0)
     assert float(y[1]) == 2.0                   # fence returns the value
-    (ev,) = obs.trace_events()
+    # the op's first call also compiles: those are jax.* spans inside
+    (ev,) = [e for e in obs.trace_events() if e["cat"] != "jax"]
     assert ev["name"] == "unit.fenced"
 
 
