@@ -44,7 +44,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..core.blocks import BlockGraph
-from ..core.graph import Graph, from_coo
+from ..core.graph import Graph, from_dst_sorted
 from ..obs.metrics import counter as _counter
 from ..obs.spans import span as _span
 
@@ -254,8 +254,8 @@ class NeighborSampler:
             n_src_pad = self.layer_sizes[li + 1]
             n_edges_pad = n_dst * fanout
             with _span("sampler.draw"):
-                kmask, eslot, _ = self._sample_layer(self.indptr, rng,
-                                                     frontier, fanout)
+                kmask, eslot, take = self._sample_layer(self.indptr, rng,
+                                                        frontier, fanout)
                 # real sampled edges in row-major (canonical) order
                 jj, kk = np.nonzero(kmask)
                 nbs = self.src[eslot[jj, kk]]
@@ -286,35 +286,31 @@ class NeighborSampler:
                 n_real = len(jj)
                 nbr = np.full((n_dst, fanout), n_src_pad - 1, np.int32)
                 nbr[jj, kk] = srcs
-                nbr_eid = np.zeros((n_dst, fanout), np.int32)
-                nbr_eid[jj, kk] = np.arange(n_real, dtype=np.int32)
+                # pad slots of the neighbor table index SOME valid edge
+                # id; they are masked, so the value never reaches a
+                # reduction
+                nbr_eid = np.full((n_dst, fanout),
+                                  min(n_real, n_edges_pad - 1), np.int32)
+                nbr_eid[kmask] = np.arange(n_real, dtype=np.int32)
                 nbr_mask = kmask
-                norms = (1.0 / np.sqrt(self.deg_out[nbs]
-                                       * self.deg_in[frontier[jj]]))
                 # pad edges into the dummy destination row n_dst (never
                 # any real source slot: a pad edge exists only when some
                 # row is under fanout, which leaves the dummy source slot
                 # free)
                 pad = n_edges_pad - n_real
-                srcs = np.concatenate(
-                    [srcs, np.full(pad, n_src_pad - 1, np.int64)])
-                dsts = np.concatenate([jj, np.full(pad, n_dst, np.int64)])
-                norms = np.concatenate([norms,
-                                        np.zeros(pad)]).astype(np.float32)
-                # pad slots of the neighbor table index SOME valid edge
-                # id; they are masked, so the value never reaches a
-                # reduction
-                nbr_eid[~nbr_mask] = min(n_real, n_edges_pad - 1)
-                real_deg = nbr_mask.sum(axis=1).astype(np.int32)
-                # reverse table: the same edge list stably sorted by
-                # source slot — what the gather backward pulls over. Pad
-                # edges (dummy source = last slot) sort last; their dst
-                # is the dummy row, so a zero cotangent row masks them
-                # exactly.
-                rev_eid = np.argsort(srcs, kind="stable").astype(np.int32)
-                rev_src = srcs[rev_eid].astype(np.int32)
-                rev_dst = dsts[rev_eid].astype(np.int32)
-                g = from_coo(srcs, dsts, n_src=n_src_pad, n_dst=n_dst + 1)
+                norms = np.zeros(n_edges_pad, np.float32)
+                norms[:n_real] = 1.0 / np.sqrt(self.deg_out[nbs]
+                                               * self.deg_in[frontier[jj]])
+                real_deg = take.astype(np.int32)    # == kmask row sums
+                # the block graph and its reverse table (the padded edge
+                # list stably sorted by source slot — what the gather
+                # backward pulls over), built from the row-major layout:
+                # only the real edges are sorted. Pad edges (dummy source
+                # = last slot) sort last; their dst is the dummy row, so
+                # a zero cotangent row masks them exactly.
+                g, (rev_src, rev_dst, rev_eid) = from_dst_sorted(
+                    srcs, jj, n_src=n_src_pad, n_dst=n_dst + 1,
+                    n_edges=n_edges_pad)
                 rel_e = rel_w = None
                 if self.rel is not None:
                     # relation id per sampled edge + the per-(dst,

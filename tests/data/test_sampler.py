@@ -16,7 +16,7 @@ import jax
 import jax.numpy as jnp
 import pytest
 
-from repro.core import block_gspmm
+from repro.core import block_gspmm, from_coo
 from repro.data import NeighborSampler, make_node_dataset
 from repro.models.gnn import gat, gcn, sage
 from repro.models.gnn.common import (block_features, make_bundle,
@@ -301,3 +301,92 @@ def test_sampled_equals_full_when_fanout_covers_degree(tiny, mod):
     ref = np.asarray(full)[np.asarray(mb.seed_ids)]
     np.testing.assert_allclose(np.asarray(sampled), ref,
                                rtol=2e-4, atol=2e-5)
+
+
+def _regular_graph(n, deg):
+    """Every node v has in-edges from v+1 … v+deg (mod n): in-degree
+    exactly ``deg`` everywhere, so fanout ``deg`` leaves no pad edge."""
+    dst = np.repeat(np.arange(n), deg)
+    src = (dst + np.tile(np.arange(1, deg + 1), n)) % n
+    return from_coo(src, dst, n_src=n, n_dst=n)
+
+
+def _equivalence_batches(case, tiny):
+    g, feats, labels, tm, vm, nc = tiny
+    ids = np.nonzero(tm)[0]
+    rng = np.random.default_rng(17)
+    if case == "full_batch":
+        return _batches(NeighborSampler(g, [4, 3], 16, seed=3), ids,
+                        labels[ids], n=2)
+    if case == "short_final_batch":
+        mbs = list(NeighborSampler(g, [3, 2], 16, seed=5).batches(
+            ids[:37], labels[ids[:37]], drop_last=False))
+        assert len(mbs) == 3 and int(np.asarray(mbs[-1].label_mask).sum()) == 5
+        return mbs[-1:]
+    if case == "fanout_covers_degree":
+        maxdeg = int(np.asarray(g.in_degrees).max())
+        return _batches(NeighborSampler(g, [maxdeg, maxdeg], 16, seed=4),
+                        ids, labels[ids], n=1)
+    if case == "no_pad_edges":
+        # 8 spread-out seeds of a 3-regular graph: both blocks are full,
+        # and the seeds' block puts a real edge in its last source slot
+        rg = _regular_graph(400, 3)
+        mb = NeighborSampler(rg, [3, 3], 8, seed=0).sample(
+            np.arange(0, 400, 50), np.zeros(8, np.int64))
+        inner = mb.blocks[-1].bg
+        assert inner.g.n_edges == int(np.asarray(inner.real_deg).sum())
+        assert int(np.asarray(inner.g.src).max()) == inner.g.n_src - 1
+        return [mb]
+    if case == "duplicate_edges":
+        # 20 nodes, 300 random edges: many parallel edges, so rows sample
+        # the same source slot more than once
+        dg, _, _ = random_graph(rng, 20, 20, 300)
+        mbs = _batches(NeighborSampler(dg, [6, 12], 8, seed=1),
+                       np.arange(20), np.zeros(20, np.int64), n=2)
+        assert any(len(set(r[m].tolist())) < int(m.sum())
+                   for mb in mbs for b in mb.blocks
+                   for r, m in zip(np.asarray(b.bg.nbr),
+                                   np.asarray(b.bg.nbr_mask)))
+        return mbs
+    assert case == "relational"
+    rel = rng.integers(0, 4, g.n_edges)
+    return _batches(NeighborSampler(g, [4, 3], 16, seed=6, edge_rel=rel),
+                    ids, labels[ids], n=2)
+
+
+@pytest.mark.parametrize("case", [
+    "full_batch", "short_final_batch", "fanout_covers_degree",
+    "no_pad_edges", "duplicate_edges", "relational"])
+def test_blocks_bit_identical_to_from_coo(tiny, case):
+    """Every sampled block's graph equals ``from_coo`` over the same
+    padded edge list, array for array and dtype for dtype, and its reverse
+    table equals a stable argsort of that list by source slot. The edge
+    list is read back from the neighbor table (row-major real edges, then
+    pads from the dummy source slot into the dummy row)."""
+    for mb in _equivalence_batches(case, tiny):
+        for blk in mb.blocks:
+            bg = blk.bg
+            mask = np.asarray(bg.nbr_mask)
+            n_real = int(mask.sum())
+            np.testing.assert_array_equal(np.asarray(bg.nbr_eid)[mask],
+                                          np.arange(n_real))
+            pad = bg.g.n_edges - n_real
+            srcs = np.concatenate([np.asarray(bg.nbr)[mask],
+                                   np.full(pad, bg.g.n_src - 1)])
+            dsts = np.concatenate([np.nonzero(mask)[0],
+                                   np.full(pad, bg.n_dst_real)])
+            ref = from_coo(srcs, dsts, n_src=bg.g.n_src,
+                           n_dst=bg.n_dst_real + 1)
+            assert ((bg.g.n_src, bg.g.n_dst, bg.g.n_edges)
+                    == (ref.n_src, ref.n_dst, ref.n_edges))
+            for f in ("src", "dst", "eid", "indptr_dst", "indptr_src",
+                      "perm_src", "eid_inv"):
+                got, want = getattr(bg.g, f), getattr(ref, f)
+                assert got.dtype == want.dtype, f
+                np.testing.assert_array_equal(np.asarray(got),
+                                              np.asarray(want), err_msg=f)
+            order = np.argsort(srcs, kind="stable")
+            for got, want in ((bg.rev_eid, order), (bg.rev_src, srcs[order]),
+                              (bg.rev_dst, dsts[order])):
+                assert got.dtype == jnp.int32
+                np.testing.assert_array_equal(np.asarray(got), want)
