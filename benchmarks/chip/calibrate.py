@@ -16,7 +16,9 @@ a summary line per mode with the largest and smallest reading):
 - ``frozen``: a step that returns its state unchanged (it reads 1 on
   ``grad`` and ``update`` by their definition).
 
-The benchmark's own runs never run these modes.
+Which faults a cell can have is its kind's to say: ``FAULTS`` in
+``kinds/<kind>.py`` names them, as modes of this file, and ``--modes
+faults`` runs them. The benchmark's own runs never run these modes.
 """
 from __future__ import annotations
 
@@ -53,9 +55,23 @@ MODES = {
     # program's bfloat16 training path
     "control_cpu": {"precision": "bf16"},
 }
-# the faults each kind of cell can have (one chip: no exchange to drop)
-FAULTS = {"full_train": ("frozen", "half"),
-          "sampled_train": ("frozen", "half")}
+
+
+def faults(workload, spec=None, bench_dir=None):
+    """The faults a cell can have: ``FAULTS`` of its kind's file, loaded
+    as the harness loads it; each has to be a mode here."""
+    import harness
+    bench_dir = bench_dir or harness.BENCH_DIR
+    if spec is None:
+        spec = harness.load_json(os.path.join(harness.ROOT,
+                                              "BENCHMARK.json"))
+    kind = harness.resolve(spec, workload, bench_dir).mix["kind"]
+    names = tuple(harness.load_kind(kind, bench_dir).FAULTS)
+    unknown = [f for f in names if f not in MODES]
+    if unknown:
+        raise ValueError(f"kinds/{kind}.py names faults {unknown} that "
+                         f"are no mode of calibrate.py ({sorted(MODES)})")
+    return names
 
 
 def calibrate(workload, seeds, modes, seconds, *, require_tpu=True,
@@ -93,10 +109,16 @@ def main(argv=None) -> int:
     ap.add_argument("--workload", required=True)
     ap.add_argument("--seeds", type=int, nargs="+", required=True)
     ap.add_argument("--modes", nargs="+", default=["sound", "control"],
-                    choices=sorted(MODES))
+                    choices=sorted(MODES) + ["faults"],
+                    help="modes to run; 'faults' stands for the faults "
+                    "the cell's kind declares in FAULTS of "
+                    "kinds/<kind>.py")
     ap.add_argument("--seconds", type=float, default=1.0)
     args = ap.parse_args(argv)
-    calibrate(args.workload, args.seeds, args.modes, args.seconds)
+    modes = []
+    for m in args.modes:
+        modes += faults(args.workload) if m == "faults" else [m]
+    calibrate(args.workload, args.seeds, modes, args.seconds)
     return 0
 
 

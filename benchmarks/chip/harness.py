@@ -10,14 +10,19 @@ by name:
   source; its ``model.arch`` names ``models/<arch>.py``, which makes the
   weights, calls the program and holds the plain reference;
 - ``mixes/<traffic>.json``: the traffic's parameters; its ``kind`` names
-  the general runner ``kinds/<kind>.py``;
+  the general runner ``kinds/<kind>.py``, which holds ``run(run)`` and
+  ``FAULTS``, the names of the ``calibrate.py`` modes that plant each
+  fault a cell of that kind can have;
 - ``limits/<cell>.json``: the limit of each number that decides
   ``correct``, with the readings it was set from;
 - ``metrics/<metric>.py``: a reader ``read(run) -> float | None`` of one
   per-layer metric.
 
-A later cell or metric is added by adding such files and entries, with
-no edit to this file.
+A later cell, kind, mix or metric is added by adding such files and
+entries, with no edit to this file. Its CPU rehearsals take
+``fixtures/<config prefix>-tiny.json`` for the configuration and, where
+it exists, ``fixtures/<traffic>-tiny.json`` for the mix
+(``test_chipbench_cells.py``).
 """
 from __future__ import annotations
 
@@ -92,6 +97,11 @@ def resolve(spec: dict, workload: str, bench_dir: str = BENCH_DIR) -> Cell:
     return Cell(name=workload, chips=int(wl["chips"]),
                 config_name=wl["config"], cfg=cfg, traffic=wl["traffic"],
                 mix=mix, limits=limits, end_to_end=e2e, per_layer=per)
+
+
+def load_kind(kind: str, bench_dir: str = BENCH_DIR):
+    """The runner of a kind of cell, ``kinds/<kind>.py``."""
+    return load_module(os.path.join(bench_dir, "kinds", kind + ".py"), kind)
 
 
 def peaks(device_kind: str, bench_dir: str = BENCH_DIR) -> dict:
@@ -260,10 +270,7 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool, *,
         setup_compile_cache()
     run = Run(cell, seed, seconds, trace, devs, t_start, hooks)
     run.bench_dir = bench_dir
-    kind = load_module(os.path.join(bench_dir, "kinds",
-                                    cell.mix["kind"] + ".py"),
-                       cell.mix["kind"])
-    kind.run(run)
+    load_kind(cell.mix["kind"], bench_dir).run(run)
 
     checks = {}
     correct = True

@@ -13,6 +13,11 @@ change are compared.
 Mix keys: ``strategy`` (the program's aggregation strategy),
 ``optimizer`` (``lr``, ``weight_decay``, ``clip``, ``b1``, ``b2``,
 ``eps`` as the program's step uses them), ``ref_steps``.
+
+``FAULTS`` names the faults a cell of this kind can have, each a mode
+of ``calibrate.py`` that plants it under the timed path: a step that
+returns its state unchanged, and half of the training rows left out of
+the loss (one chip: there is no exchange between chips to leave out).
 """
 from __future__ import annotations
 
@@ -22,6 +27,7 @@ import os
 import numpy as np
 
 N_KEYS = 8192
+FAULTS = ("frozen", "half")
 
 
 def run(run) -> None:
@@ -96,6 +102,8 @@ def run(run) -> None:
     run.layer["steps"] = n
     run.layer["flops_per_step"] = arch.flops_per_epoch(cfg, gd.n,
                                                        gd.n_edges)
+    run.layer["aggregate_bytes_per_step"] = arch.aggregate_bytes_per_epoch(
+        cfg, gd.n, gd.n_edges)
 
     # free the program's state before the reference takes the chip
     del params, opt, loss, losses, opt_after_first, bundle, g, step

@@ -19,6 +19,10 @@ blocks, from the same weights and dropout keys.
 Mix keys: ``fanouts`` (outermost hop first, so the last is the seeds'
 hop), ``batch_size``, ``prefetch_depth``, ``strategy``,
 ``bwd_strategy``, ``optimizer``, ``ref_steps``.
+
+``FAULTS`` names the faults a cell of this kind can have (modes of
+``calibrate.py``): a step that returns its state unchanged, and half of
+the training rows left out of the loss (one chip: no exchange).
 """
 from __future__ import annotations
 
@@ -29,6 +33,7 @@ import time
 import numpy as np
 
 N_KEYS = 8192
+FAULTS = ("frozen", "half")
 
 
 def _epochs(sampler, train_ids, labels, batch, depth, prefetch):

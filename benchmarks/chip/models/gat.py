@@ -118,3 +118,27 @@ def flops_per_epoch(cfg: dict, n: int, n_edges: int) -> float:
             + 2.0 * n_edges * heads * width
         total += 3 * (proj + rest) - (proj if i == 0 else 0.0)
     return total
+
+
+def aggregate_bytes_per_epoch(cfg: dict, n: int, n_edges: int) -> float:
+    """Bytes the attention aggregation of one full-graph training step
+    must move, counted from shapes (float32 values, int32 indices), as a
+    fused kernel would move them: per layer of ``H`` heads of width
+    ``F`` and per direction, one source row of ``z`` and of the logit
+    half ``el`` read per edge slot (``H * (F + 1)`` values) and its
+    source index; per destination its ``er`` read (``H``) and its output
+    row written (``H * F`` forward; backward the output's gradient read
+    and, written, ``dz`` and the gradients of both logit halves,
+    ``H * F + 2H``). Backward runs on every layer: the first layer's
+    projection and attention vectors take gradients through it. The
+    per-edge logits and attention weights, which a multi-pass
+    implementation writes and reads again, are left out, so this is a
+    lower bound on the traffic."""
+    total = 0.0
+    for _, heads, width in shapes(cfg):
+        per_edge = 4.0 * n_edges * (heads * (width + 1) + 1)
+        fwd = per_edge + 4.0 * n * (heads + heads * width)
+        bwd = per_edge + 4.0 * n * (heads + heads * width
+                                    + heads * width + 2 * heads)
+        total += fwd + bwd
+    return total

@@ -114,3 +114,20 @@ def flops_per_epoch(cfg: dict, n: int, n_edges: int) -> float:
         if i > 0:
             total += dense + agg         # dX through dense and mean
     return total
+
+
+def aggregate_bytes_per_epoch(cfg: dict, n: int, n_edges: int) -> float:
+    """Bytes the neighbour means of one full-graph training step must
+    move, counted from shapes (float32 values, int32 indices): per mean,
+    one source row read per edge slot at the layer's input width, one
+    source index and one mean weight per edge slot, and one row written
+    per destination. Forward every layer; backward the mean's adjoint of
+    every layer but the first (the input features take no gradient), a
+    mean over the reverse graph at the same width. What an
+    implementation chooses to materialise (padded slots, per-slot
+    messages) is left out, so this is a lower bound on the traffic."""
+    total = 0.0
+    for i, a in enumerate(dims(cfg)[:-1]):
+        one = 4.0 * (n_edges * a + 2 * n_edges + n * a)
+        total += one * (2 if i > 0 else 1)
+    return total

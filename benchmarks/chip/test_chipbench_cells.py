@@ -23,8 +23,10 @@ SECONDS = 0.3
 
 
 def tiny_spec():
-    """The benchmark's spec with each configuration cut to a CPU size
-    (``fixtures/<arch>-tiny.json``)."""
+    """The benchmark's spec with each configuration cut to a CPU size:
+    ``fixtures/<prefix>-tiny.json``, the prefix being the
+    configuration's name up to its first ``-`` (a configuration of a
+    new model brings its own)."""
     spec = harness.load_json(os.path.join(ROOT, "BENCHMARK.json"))
     for c in spec["configs"]:
         c["file"] = os.path.join(HERE, "fixtures",
@@ -32,16 +34,19 @@ def tiny_spec():
     return spec
 
 
-def tiny_bench(tmp_path):
-    """A copy of the benchmark directory whose sampled mix is cut to a
-    CPU size (``fixtures/sampled-tiny.json``); everything else is as
-    committed."""
+def tiny_bench(tmp_path, src=HERE):
+    """A copy of the benchmark directory ``src`` in which each mix
+    ``<traffic>`` that has a ``fixtures/<traffic>-tiny.json`` is
+    replaced by that fixture, cut to a CPU size; everything else is as
+    in ``src``."""
     dst = tmp_path / "chip"
-    shutil.copytree(HERE, dst, ignore=shutil.ignore_patterns(
+    shutil.copytree(src, dst, ignore=shutil.ignore_patterns(
         "__pycache__", "test_*.py"))
-    mix = harness.load_json(os.path.join(HERE, "fixtures",
-                                         "sampled-tiny.json"))
-    (dst / "mixes" / "sampled.json").write_text(json.dumps(mix))
+    for name in os.listdir(dst / "mixes"):
+        traffic, ext = os.path.splitext(name)
+        tiny = dst / "fixtures" / f"{traffic}-tiny.json"
+        if ext == ".json" and tiny.exists():
+            shutil.copyfile(tiny, dst / "mixes" / name)
     return str(dst)
 
 

@@ -1,8 +1,10 @@
 """Each fault a cell can have, planted under the timed path at a tiny
 size on the CPU, makes ``correct`` come out false under the cell's
-committed limits: a step that returns its state unchanged, and half of
-the training rows left out of the loss with the mean taken over the
-rest (one chip: no exchange between chips to leave out)."""
+committed limits. The faults are those the cell's kind declares in
+``FAULTS`` of ``kinds/<kind>.py``; the training kinds name a step that
+returns its state unchanged, and half of the training rows left out of
+the loss with the mean taken over the rest (one chip: no exchange
+between chips to leave out)."""
 import os
 import sys
 
@@ -26,8 +28,7 @@ def bench(tmp_path_factory):
 def _faults():
     spec = tiny_spec()
     return [(cell, fault) for cell in CELLS
-            for fault in calibrate.FAULTS[harness.resolve(
-                spec, cell).mix["kind"]]]
+            for fault in calibrate.faults(cell, spec)]
 
 
 @pytest.mark.parametrize("cell,fault", _faults())
